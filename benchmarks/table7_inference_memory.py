@@ -17,9 +17,10 @@ bytes can sit slightly above q/8 per layer: the conv layout pads each
 A MEASURED SHARDED-SERVING section scales the claim over a tensor-parallel
 mesh: the packed tile rows of a reduced LM shard over the model axis
 (DESIGN.md §5) and we report per-device resident tile bytes, decode tick
-latency, and the max |logit| deviation vs the single-device path. It runs
-in a subprocess because the 8 forced host devices must be configured
-before jax initializes (the same trick the multi-device tests use).
+latency, and the max |logit| deviation vs the single-device path. On the
+CPU it runs in a subprocess because the 8 forced host devices must be
+configured before jax initializes (the same trick the multi-device tests
+use); on an accelerator it runs in-process on the host's chips.
 
 A MEASURED CHUNKED-PREFILL section runs the mixed workload the serving
 scheduler exists for: one slot decoding while a long prompt streams in
@@ -40,8 +41,8 @@ A MEASURED DECODE-BLOCKING section times the decode hot path's matmul at
 serving batch sizes: the old route padded an (n_slots, 1) decode batch to
 the matmul kernel's 128-row m block (~97% zero rows at 4 slots); the
 small-m dispatch in ``ops.tiled_dense_infer`` now routes those batches to
-``tiled_matvec_unique`` (whole sublane-rounded batch as one m block,
-widened r/k blocking). Both paths run the same backend (Pallas on TPU,
+``tiled_matvec_unique`` (whole sublane-rounded batch as one m block).
+Both paths run the same backend (Pallas on TPU,
 interpret elsewhere), so the reported delta is the blocking's, not the
 platform's."""
 from __future__ import annotations
@@ -61,101 +62,125 @@ from repro.nn.context import ModelContext
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
+
+def sharded_rows(tps, ticks):
+    """Per-device tile bytes, decode tick latency and the max |logit|
+    deviation from TP=1, for each tensor-parallel degree in ``tps``, on
+    this process's devices."""
+    import contextlib
+    import time
+
+    import jax
+    import numpy as np
+
+    from repro.configs import build_model, get_config
+    from repro.distributed.sharding import axis_rules, param_shardings
+    from repro.launch.mesh import make_auto_mesh
+    from repro.nn import module as mod
+    from repro.nn.context import SERVE, TRAIN
+    from repro.serve.weights import (
+        export_serving_params, per_device_tile_bytes, tile_serving_bytes)
+
+    cfg = get_config("granite-8b").reduced()
+    tm = build_model(cfg, ModelContext(policy=cfg.tbn, mode=TRAIN,
+                                       compute_dtype=jnp.float32))
+    sm = build_model(cfg, ModelContext(policy=cfg.tbn, mode=SERVE,
+                                       compute_dtype=jnp.float32,
+                                       use_pallas=False))
+    tp0 = mod.init_params(tm.specs(), jax.random.PRNGKey(0))
+    sp = export_serving_params(tm.specs(), sm.specs(), tp0, cfg.tbn)
+    batch = {"tokens": jnp.array([[5, 3, 2, 7, 1, 4, 6, 2]], jnp.int32)}
+    logical = mod.logical_axes(sm.specs())
+    abstract = jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), sp)
+    total_tile = tile_serving_bytes(sp)
+
+    rows, ref_logits = [], None
+    for tp in tps:
+        if tp == 1:
+            mesh, params = None, sp
+        else:
+            mesh = make_auto_mesh((tp,), ("model",))
+            params = jax.device_put(
+                sp, param_shardings(mesh, logical, abstract_tree=abstract))
+        ctx = axis_rules(mesh) if mesh is not None else contextlib.nullcontext()
+        with ctx:
+            prefill = jax.jit(lambda p, b: sm.prefill(p, b, 16))
+            decode = jax.jit(sm.decode_step)
+            logits, caches, lengths = prefill(params, batch)
+            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            lg, caches, lengths = decode(params, tok, caches, lengths)  # compile
+            lg.block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(ticks):
+                lg, caches, lengths = decode(params, tok, caches, lengths)
+            lg.block_until_ready()
+            tick_ms = 1e3 * (time.perf_counter() - t0) / ticks
+        if ref_logits is None:
+            ref_logits = np.asarray(logits, np.float32)
+            diff = 0.0
+        else:
+            diff = float(np.max(np.abs(
+                ref_logits - np.asarray(logits, np.float32))))
+        per_dev = per_device_tile_bytes(params)
+        worst = max(per_dev.values())
+        rows.append(dict(
+            tp=tp,
+            tile_kb_total=round(total_tile / 1e3, 2),
+            tile_kb_per_device=round(worst / 1e3, 2),
+            sharding=f"{total_tile / worst:.1f}x",
+            tick_ms=round(tick_ms, 1),
+            max_logit_diff=f"{diff:.2e}",
+        ))
+    return rows
+
+
+# CPU only: the 8 forced host devices must be configured before jax
+# initializes, so the section runs in a fresh interpreter
 _SHARDED_PROG = """
 import os
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", ""))
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_auto_mesh
-from repro.configs import build_model, get_config
-from repro.distributed.sharding import axis_rules, param_shardings
-from repro.nn import module as mod
-from repro.nn.context import SERVE, TRAIN, ModelContext
-from repro.serve.weights import (
-    export_serving_params, per_device_tile_bytes, tile_serving_bytes)
-import contextlib
-
-TPS = %(tps)s
-TICKS = %(ticks)d
-cfg = get_config("granite-8b").reduced()
-tm = build_model(cfg, ModelContext(policy=cfg.tbn, mode=TRAIN,
-                                   compute_dtype=jnp.float32))
-sm = build_model(cfg, ModelContext(policy=cfg.tbn, mode=SERVE,
-                                   compute_dtype=jnp.float32,
-                                   use_pallas=False))
-tp0 = mod.init_params(tm.specs(), jax.random.PRNGKey(0))
-sp = export_serving_params(tm.specs(), sm.specs(), tp0, cfg.tbn)
-batch = {"tokens": jnp.array([[5, 3, 2, 7, 1, 4, 6, 2]], jnp.int32)}
-logical = mod.logical_axes(sm.specs())
-abstract = jax.tree.map(lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), sp)
-total_tile = tile_serving_bytes(sp)
-
-rows, ref_logits = [], None
-for tp in TPS:
-    if tp == 1:
-        mesh, params = None, sp
-    else:
-        mesh = make_auto_mesh((tp,), ("model",))
-        params = jax.device_put(
-            sp, param_shardings(mesh, logical, abstract_tree=abstract))
-    ctx = axis_rules(mesh) if mesh is not None else contextlib.nullcontext()
-    with ctx:
-        prefill = jax.jit(lambda p, b: sm.prefill(p, b, 16))
-        decode = jax.jit(sm.decode_step)
-        logits, caches, lengths = prefill(params, batch)
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        lg, caches, lengths = decode(params, tok, caches, lengths)  # compile
-        lg.block_until_ready()
-        t0 = time.perf_counter()
-        for _ in range(TICKS):
-            lg, caches, lengths = decode(params, tok, caches, lengths)
-        lg.block_until_ready()
-        tick_ms = 1e3 * (time.perf_counter() - t0) / TICKS
-    if ref_logits is None:
-        ref_logits = np.asarray(logits, np.float32)
-        diff = 0.0
-    else:
-        diff = float(np.max(np.abs(ref_logits - np.asarray(logits, np.float32))))
-    per_dev = per_device_tile_bytes(params)
-    worst = max(per_dev.values())
-    rows.append(dict(
-        tp=tp,
-        tile_kb_total=round(total_tile / 1e3, 2),
-        tile_kb_per_device=round(worst / 1e3, 2),
-        sharding=f"{total_tile / worst:.1f}x",
-        tick_ms=round(tick_ms, 1),
-        max_logit_diff=f"{diff:.2e}",
-    ))
-print("SHARDED_JSON=" + json.dumps(rows))
+import json
+from benchmarks.table7_inference_memory import sharded_rows
+print("SHARDED_JSON=" + json.dumps(sharded_rows(%(tps)s, %(ticks)d)))
 """
 
 
 def measure_sharded_serving(quick: bool):
     """Per-device tile bytes + decode tick latency over a model-axis mesh.
 
-    Returns the benchmark rows, or None when the subprocess fails (the
-    main table still prints — the sharded section is additive)."""
+    On an accelerator the section runs in this process on the host's own
+    devices: a child could not open the chips this process holds. On the
+    CPU it runs in a child with 8 forced host devices. Either way a
+    failure raises — the section never reports itself skipped."""
+    import jax
+
     tps = [1, 4] if quick else [1, 2, 4]
-    prog = _SHARDED_PROG % dict(tps=tps, ticks=4 if quick else 16)
+    ticks = 4 if quick else 16
+    if jax.default_backend() != "cpu":
+        n_dev = len(jax.devices())
+        if n_dev < max(tps):
+            raise RuntimeError(
+                f"sharded serving section needs {max(tps)} devices, this "
+                f"host has {n_dev}")
+        return sharded_rows(tps, ticks)
     env = dict(os.environ)
-    env["PYTHONPATH"] = str(ROOT / "src") + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c", prog], capture_output=True, text=True,
-            cwd=str(ROOT), timeout=900, env=env,
-        )
-    except subprocess.TimeoutExpired:
-        print("sharded serving section skipped: subprocess timed out")
-        return None
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(ROOT / "src"), str(ROOT), env.get("PYTHONPATH", "")])
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c", _SHARDED_PROG % dict(tps=tps, ticks=ticks)],
+        capture_output=True, text=True, cwd=str(ROOT), timeout=900, env=env,
+    )
     for line in out.stdout.splitlines():
         if line.startswith("SHARDED_JSON="):
             return json.loads(line[len("SHARDED_JSON="):])
-    print(f"sharded serving section skipped: rc={out.returncode}\n"
-          f"{out.stderr[-2000:]}")
-    return None
+    raise RuntimeError(
+        f"sharded serving section failed: rc={out.returncode}\n"
+        f"{out.stderr[-2000:]}")
+
 
 def measure_decode_blocking(quick: bool):
     """Old 128-row matmul blocking vs the small-m matvec dispatch at
@@ -522,12 +547,11 @@ def run(quick: bool = False):
     # measured tensor-parallel serving: tile rows sharded over the model
     # axis — per-device bytes must scale as 1/TP with unchanged logits
     srows = measure_sharded_serving(quick)
-    if srows:
-        save_rows("table7_sharded_serving", srows)
-        print("\nmeasured sharded serving (reduced LM, tile rows over the "
-              "model axis, 8 forced host devices):")
-        print(fmt_table(srows, ["tp", "tile_kb_total", "tile_kb_per_device",
-                                "sharding", "tick_ms", "max_logit_diff"]))
+    save_rows("table7_sharded_serving", srows)
+    print("\nmeasured sharded serving (reduced LM, tile rows over the "
+          "model axis):")
+    print(fmt_table(srows, ["tp", "tile_kb_total", "tile_kb_per_device",
+                            "sharding", "tick_ms", "max_logit_diff"]))
     return rows
 
 

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -152,7 +151,7 @@ def build_dp_train_step(
     rep = P()
     batch_spec = {"x": P(dp_axis), "y": P(dp_axis)}
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(rep, rep, batch_spec),

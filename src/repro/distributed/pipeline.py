@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_forward(
@@ -98,7 +97,7 @@ def build_gpipe_apply(
         local = jax.tree.map(lambda v: v[0], stacked_params)  # our stage slice
         return pipeline_forward(stage_fn, local, x, axis=axis)
 
-    return shard_map(
+    return jax.shard_map(
         apply,
         mesh=mesh,
         in_specs=(params_spec, P()),

@@ -224,6 +224,36 @@ def named_sharding(mesh: Mesh, *axes: AxisVal) -> NamedSharding:
     return NamedSharding(mesh, P(*axes))
 
 
+# K/V cache leaves by key -> the axis holding the KV heads: (..., kv, hd)
+# for k/v, (..., kv) for the int8 scales
+_KV_HEAD_AXIS = {"k": -2, "v": -2, "ks": -1, "vs": -1}
+
+
+def cache_shardings(mesh: Mesh, caches, rules=None):
+    """NamedShardings for a tree of serving caches (arrays or avals).
+
+    Every K/V leaf shards its KV-head axis where ``act_kv_heads`` maps —
+    the heads each chip computes under tensor parallelism — so a chip
+    holds 1/TP of the KV pool and a tick's cache writes stay local. All
+    other axes and leaves (recurrent state) are replicated: pool pages
+    are shared by every slot through the page table, so they do not
+    split over the data axis.
+    """
+    rules = dict(DEFAULT_RULES, **(rules or {}))
+    heads = rules.get("act_kv_heads")
+    heads = (heads,) if isinstance(heads, str) else tuple(heads or ())
+    heads = tuple(a for a in heads if a in mesh.axis_names) or None
+
+    def one(path, leaf):
+        spec = [None] * len(leaf.shape)
+        key = getattr(path[-1], "key", None) if path else None
+        if heads and key in _KV_HEAD_AXIS:
+            spec[_KV_HEAD_AXIS[key]] = heads
+        return NamedSharding(mesh, _divisible_spec(mesh, leaf.shape, spec))
+
+    return jax.tree_util.tree_map_with_path(one, caches)
+
+
 def param_shardings(mesh: Mesh, logical_tree, rules=None, abstract_tree=None):
     """Map a tree of logical-axis tuples to NamedShardings for jit.
 

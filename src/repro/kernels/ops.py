@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.packing import pack_bits, unpack_bits, unpack_conv_tile
 from repro.core.tiling import (
     TileSpec,
@@ -59,7 +58,7 @@ from repro.core.tiling import (
 from repro.distributed.sharding import batch_shard_axes, tile_sharding
 from repro.kernels.tile_construct import tile_construct_pallas
 from repro.kernels.tiled_conv import tiled_conv_unique
-from repro.kernels.tiled_matmul import tiled_matmul_unique
+from repro.kernels.tiled_matmul import LANE_BITS, WORD_BLOCK, tiled_matmul_unique
 from repro.kernels.tiled_matvec import (
     DECODE_BLOCK_K,
     DECODE_BLOCK_R,
@@ -204,15 +203,20 @@ def _dense_unique_local(
         return tiled_matvec_unique(
             xp, tm_p, r=tm_p.shape[0], block_r=br, block_k=bk,
         )[:m, :r_loc]
-    xp = _pad_to(_pad_to(xp, 0, block_m), 1, block_k)
-    tm_p = _pad_to(_pad_to(packed_rows, 0, block_r), 1, block_k // 32)
+    # k blocks: the whole padded row when it fits one block, else block_k
+    # steps over a row padded to a multiple (the Mosaic block rule,
+    # kernels/tiled_matmul.py); r likewise
+    br = min(block_r, r_loc)
+    bk = min(block_k, words * 32)
+    xp = _pad_to(_pad_to(xp, 0, block_m), 1, bk)
+    tm_p = _pad_to(_pad_to(packed_rows, 0, br), 1, bk // 32)
     return tiled_matmul_unique(
         xp,
         tm_p,
         r=tm_p.shape[0],
         block_m=block_m,
-        block_r=block_r,
-        block_k=block_k,
+        block_r=br,
+        block_k=bk,
     )[:m, :r_loc]
 
 
@@ -236,7 +240,7 @@ def tiled_dense_infer(
     use_pallas: Optional[bool] = None,
     block_m: int = 128,
     block_r: int = 128,
-    block_k: int = 512,
+    block_k: int = WORD_BLOCK * LANE_BITS,
     compute_path: str = "float",
 ) -> jax.Array:
     """y = x @ W_hat^T from the shipped representation.
@@ -296,7 +300,7 @@ def tiled_dense_infer(
     if tp is not None:
         mesh, ax, _ = tp
         m_ax = batch_shard_axes(ax, m) or None
-        y3 = shard_map(
+        y3 = jax.shard_map(
             lambda xl, pl_, al: _replicate_dense_out(local(xl, pl_), al, spec),
             mesh=mesh,
             in_specs=(P(m_ax, None), P(ax, None), P()),
@@ -438,7 +442,7 @@ def tiled_conv_infer(
     if tp is not None:
         mesh, ax, _ = tp
         n_ax = batch_shard_axes(ax, n) or None
-        y5 = shard_map(
+        y5 = jax.shard_map(
             lambda xl, pl_, al: _replicate_conv_out(local_u(xl, pl_), al, spec),
             mesh=mesh,
             in_specs=(P(n_ax, None, None, None), P(None, ax, None), P()),

@@ -20,8 +20,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 LANE_BITS = 32
 
@@ -81,7 +81,7 @@ def tile_construct_pallas(
             jax.ShapeDtypeStruct((1, q // LANE_BITS), jnp.int32),
             jax.ShapeDtypeStruct((1, p), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -10,9 +10,16 @@ feeds the MXU:
     u = x @ T^T          -- p-fold fewer FLOPs than the dense layer
     y = kron(alpha, u)   -- broadcast-scale applied by the wrapper (ops.py)
 
-Weight HBM traffic is 32*p smaller than fp32 (p smaller than 1-bit BWNN);
-the VMEM working set is (bm*bk + br*bk/32 + bm*br) elements — block sizes
-default to MXU-aligned (128) multiples and are sweepable for the perf loop.
+Weight HBM traffic is 32*p smaller than fp32 (p smaller than 1-bit BWNN).
+
+Block shapes follow the Mosaic rule for the last two dims of every block
+(a multiple of (8, 128), or the whole array dim): the packed-word axis is
+blocked as the whole padded row when it has at most ``WORD_BLOCK`` words,
+else in ``WORD_BLOCK``-word steps over a row padded to a multiple (ops.py
+pads). The defaults (bm, br, bk) = (128, 128, 4096) keep the per-step
+working set -- activation block, packed block and the (br, bk) unpack
+temporaries -- inside the 16 MiB default scoped VMEM of a v5e core
+(tests/test_tpu_compile.py compiles them at granite-8b widths).
 
 Grid: (M/bm, r/br, K/bk), k innermost (sequential accumulation); m/r are
 parallel. The f32 accumulator lives in VMEM scratch.
@@ -27,9 +34,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 LANE_BITS = 32
+# Packed words per k step once a row outgrows one block: 128 words (4096
+# weight bits) is one full lane tile, the smallest legal partial block.
+WORD_BLOCK = 128
 
 
 def _unpack_block(words: jax.Array, br: int, bk: int, dtype) -> jax.Array:
@@ -43,7 +51,8 @@ def _unpack_block(words: jax.Array, br: int, bk: int, dtype) -> jax.Array:
     rep = jnp.broadcast_to(u32[:, :, None], (br, nw, LANE_BITS)).reshape(br, bk)
     shift = jax.lax.broadcasted_iota(jnp.uint32, (br, bk), 1) % LANE_BITS
     bits = (rep >> shift) & jnp.uint32(1)
-    return (bits.astype(jnp.int8) * 2 - 1).astype(dtype)
+    # a select, not ``bits * 2 - 1``: Mosaic has no integer multiply
+    return jnp.where(bits == 1, 1.0, -1.0).astype(dtype)
 
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int, compute_dtype):
@@ -73,7 +82,7 @@ def tiled_matmul_unique(
     r: int,
     block_m: int = 128,
     block_r: int = 128,
-    block_k: int = 512,
+    block_k: int = WORD_BLOCK * LANE_BITS,
     interpret: Optional[bool] = None,
     out_dtype=jnp.float32,
 ) -> jax.Array:
@@ -110,7 +119,7 @@ def tiled_matmul_unique(
         out_specs=pl.BlockSpec((block_m, block_r), lambda mi, ri, ki: (mi, ri)),
         out_shape=jax.ShapeDtypeStruct((m, r), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_r), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

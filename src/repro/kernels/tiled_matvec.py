@@ -5,15 +5,13 @@ decode tick is an ``(n_slots, 1)`` batch — at the matmul kernel's default
 ``block_m=128`` the activation block is ~97% zero padding for the default
 4 slots, and every MXU pass wastes the m dimension on rows that do not
 exist. Here the whole sublane-rounded batch IS the m block (no m grid
-axis, no m padding beyond the hardware sublane), and the freed VMEM goes
-into wider ``block_r`` / ``block_k`` so each sequential k step amortizes
-the bit-unpack (the dominant cost at small m — the kernel is
-unpack-bound, not MXU-bound) over more output columns.
+axis, no m padding beyond the hardware sublane).
 
 Grid: (r/br, K/bk), k innermost (sequential accumulation), r parallel.
 VMEM per step: m·bk activations + br·bk/32 packed words + br·bk unpacked
-weights + m·br f32 accumulator — at the decode defaults (m<=32, br=256,
-bk=1024) ~1.3 MB, far under the ~16 MB/core budget.
+weights + m·br f32 accumulator, plus the u32 temporaries of the unpack —
+at the decode defaults (m<=32, br=128, bk=4096) inside the 16 MiB default
+scoped VMEM of a v5e core.
 
 Dispatch lives in ``ops.tiled_dense_infer``: any matmul with
 m <= MATVEC_MAX_M (after flattening lead dims; per-shard m under the
@@ -30,18 +28,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from repro.kernels.tiled_matmul import LANE_BITS, _unpack_block
+from repro.kernels.tiled_matmul import LANE_BITS, WORD_BLOCK, _unpack_block
 
 # Dispatch threshold: batches at or under this m take the decode path.
 # 32 covers any realistic slot count while staying well inside the regime
 # where the matmul kernel's 128-row m blocks are mostly padding.
 MATVEC_MAX_M = 32
-# Decode-tuned blocking: wider than the matmul defaults (128, 512) —
-# with m tiny the accumulator and activation blocks are nearly free, so
-# the unpack-dominant regime wants bigger weight blocks per grid step.
-DECODE_BLOCK_R = 256
-DECODE_BLOCK_K = 1024
+# Decode blocking: one WORD_BLOCK of packed words (the Mosaic minimum
+# for a partial row, tiled_matmul.py) per k step. br=256 at that bk would
+# overrun the 16 MiB default scoped VMEM with the (br, bk) unpack
+# temporaries, so r blocks like the matmul kernel's.
+DECODE_BLOCK_R = 128
+DECODE_BLOCK_K = WORD_BLOCK * LANE_BITS
 
 
 # Smallest second-to-last dim a TPU tile supports, per dtype: 4-byte
@@ -135,7 +133,7 @@ def tiled_matvec_unique(
         out_specs=pl.BlockSpec((m, block_r), lambda ri, ki: (0, ri)),
         out_shape=jax.ShapeDtypeStruct((m, r), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, block_r), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

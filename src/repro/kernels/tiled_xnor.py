@@ -46,24 +46,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.packing import pack_bits
+from repro.kernels.tiled_matmul import LANE_BITS, WORD_BLOCK
 from repro.kernels.tiled_matvec import sublane_rounded
 
-LANE_BITS = 32
 # Dispatchable compute paths for the tiled dense serve apply. "float" is
 # the byte-parity reference (the existing unpack + MXU float kernels);
 # the integer paths engage only at decode m (ops.py falls back to float
 # for prefill-sized batches).
 COMPUTE_PATHS = ("float", "int8", "xnor")
 
-# Decode-tuned blocking. The xnor kernel blocks over packed WORDS (one
-# word = 32 weight bits): 32 words = 1024 bits per sequential step, same
-# k footprint as the float matvec's DECODE_BLOCK_K.
+# Decode blocking. The xnor kernel blocks over packed WORDS (one word = 32
+# weight bits) on the lane axis of the activation block, so a partial row
+# steps in WORD_BLOCK words (the Mosaic minimum, tiled_matmul.py) — the
+# same k footprint as the float matvec's DECODE_BLOCK_K.
 XNOR_BLOCK_R = 256
-XNOR_BLOCK_W = 32
-INT8_BLOCK_R = 256
-INT8_BLOCK_K = 1024
+XNOR_BLOCK_W = WORD_BLOCK
+INT8_BLOCK_R = 128
+INT8_BLOCK_K = WORD_BLOCK * LANE_BITS
 
 
 # --------------------------------------------------------------------------
@@ -185,7 +185,7 @@ def tiled_xnor_matvec_unique(
         out_specs=pl.BlockSpec((m, block_r), lambda ri, ki: (0, ri)),
         out_shape=jax.ShapeDtypeStruct((m, r), jnp.int32),
         scratch_shapes=[pltpu.VMEM((m, block_r), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -274,7 +274,7 @@ def tiled_int8_matvec_unique(
         out_specs=pl.BlockSpec((m, block_r), lambda ri, ki: (0, ri)),
         out_shape=jax.ShapeDtypeStruct((m, r), jnp.int32),
         scratch_shapes=[pltpu.VMEM((m, block_r), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -85,14 +85,14 @@ def run_cell(arch: str, shape: str, mesh_kind: str, roofline: bool = True) -> di
         + mem.output_size_in_bytes
         - mem.alias_size_in_bytes
     )
-    full_terms = analyze_compiled(compiled)
+    full_terms = analyze_compiled(compiled, hw.V5E)
     rec.update(
         status="ok",
         chips=chips,
         compile_s=round(t_full, 1),
         memory=mem_rec,
         peak_bytes_per_device=int(peak),
-        fits_hbm=bool(peak <= hw.HBM_BYTES),
+        fits_hbm=bool(peak <= hw.peaks(hw.V5E).hbm_bytes),
         full_cost=full_terms.as_dict(),
     )
 
@@ -100,8 +100,8 @@ def run_cell(arch: str, shape: str, mesh_kind: str, roofline: bool = True) -> di
         t1 = time.time()
         c1 = _compile(arch, shape, mesh, depth=1, exact=True)
         c2 = _compile(arch, shape, mesh, depth=2, exact=True)
-        terms1 = analyze_compiled(c1)
-        terms2 = analyze_compiled(c2)
+        terms1 = analyze_compiled(c1, hw.V5E)
+        terms2 = analyze_compiled(c2, hw.V5E)
         delta = subtract(terms2, terms1)
         trips = scan_trips(cfg)
         total = combine_extrapolated(terms1, delta, trips - 1)

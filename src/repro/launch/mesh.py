@@ -7,7 +7,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_auto_mesh
+
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types: sharding propagates from
+    the placed inputs and the logical constraints, as every caller here
+    assumes (the installed jax defaults to Explicit axes)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

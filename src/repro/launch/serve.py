@@ -59,6 +59,7 @@ import numpy as np
 
 from repro.configs import build_model, get_config
 from repro.ft.checkpoint import latest_step, restore_into
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import parse_mesh_arg
 from repro.nn import module as mod
 from repro.nn.context import SERVE, TRAIN, ModelContext
@@ -219,9 +220,9 @@ def main(argv=None):
     if encdec and args.enc_sources < 1:
         raise SystemExit(f"--enc-sources must be >= 1: {args.enc_sources}")
 
+    use_compile_cache()
     t_model = build_model(cfg, ModelContext(policy=cfg.tbn, mode=TRAIN))
     s_model = build_model(cfg, ModelContext(policy=cfg.tbn, mode=SERVE,
-                                            use_pallas=False,
                                             compute_path=args.compute_path))
     if args.compute_path != "float":
         print(f"compute path: {args.compute_path} (decode ticks quantize "
@@ -338,7 +339,8 @@ def main(argv=None):
     tok = sum(len(r.output) for r in reqs)
     # a ~0s drain (tiny reduced config, everything cached) must not
     # divide-by-zero the throughput line
-    rate = f"{tok / dt:.1f} tok/s on CPU" if dt > 1e-9 else "instant drain"
+    rate = (f"{tok / dt:.1f} tok/s on {jax.devices()[0].platform}"
+            if dt > 1e-9 else "instant drain")
     print(f"{len(reqs)} requests, {tok} tokens in {ticks} engine ticks, "
           f"{dt:.2f}s ({rate})")
     ttfts, itls = latency_report(reqs, tick_ends)

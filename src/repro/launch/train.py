@@ -28,6 +28,7 @@ from repro.distributed.sharding import axis_rules
 from repro.ft.checkpoint import CheckpointManager
 from repro.ft.recovery import RecoveryManager
 from repro.ft.watchdog import StepWatchdog
+from repro.launch.cache import use_compile_cache
 from repro.nn import module as mod
 from repro.nn.context import TRAIN, ModelContext
 from repro.optim import adamw, cosine_with_warmup
@@ -63,6 +64,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default=None,
                     help="e.g. '2x4' data x model over local devices")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

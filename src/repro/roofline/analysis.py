@@ -63,6 +63,7 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 
 @dataclasses.dataclass
 class RooflineTerms:
+    chip: hw.ChipPeaks            # the peaks the times are taken against
     flops: float                  # per-device float ops
     bytes_hbm: float              # per-device
     bytes_coll: float             # per-device
@@ -76,19 +77,19 @@ class RooflineTerms:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / hw.PEAK_FLOPS_BF16
+        return self.flops / self.chip.flops_bf16
 
     @property
     def t_int(self) -> float:
-        return self.int_ops / hw.PEAK_OPS_INT8
+        return self.int_ops / self.chip.ops_int8
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_hbm / hw.HBM_BW
+        return self.bytes_hbm / self.chip.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.bytes_coll / hw.ICI_BW
+        return self.bytes_coll / self.chip.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -147,16 +148,16 @@ def integer_dense_ops(
     raise ValueError(f"unknown compute_path {compute_path!r}")
 
 
-def analyze_compiled(compiled, int_ops: float = 0.0) -> RooflineTerms:
-    """Roofline terms from a compiled artifact.
+def analyze_compiled(compiled, device_kind: str,
+                     int_ops: float = 0.0) -> RooflineTerms:
+    """Roofline terms from a compiled artifact, against the published
+    peaks of ``device_kind`` (roofline/hw.py; unlisted kinds raise).
 
     ``int_ops`` attaches the analytic integer-op count (see
     ``integer_dense_ops``) for programs using the integer compute paths
     — cost_analysis reports those ops as zero FLOPs.
     """
-    from repro.compat import cost_analysis_dict
-
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     try:
@@ -165,6 +166,7 @@ def analyze_compiled(compiled, int_ops: float = 0.0) -> RooflineTerms:
         text = ""
     coll = collective_bytes(text)
     return RooflineTerms(
+        chip=hw.peaks(device_kind),
         flops=flops,
         bytes_hbm=nbytes,
         bytes_coll=sum(coll.values()),
@@ -182,6 +184,7 @@ def combine_extrapolated(
     for k, v in delta.coll_breakdown.items():
         coll[k] = coll.get(k, 0.0) + extra_trips * v
     return RooflineTerms(
+        chip=base.chip,
         flops=add(base.flops, delta.flops),
         bytes_hbm=add(base.bytes_hbm, delta.bytes_hbm),
         bytes_coll=add(base.bytes_coll, delta.bytes_coll),
@@ -194,6 +197,7 @@ def subtract(a: RooflineTerms, b: RooflineTerms) -> RooflineTerms:
     coll = {k: max(0.0, v - b.coll_breakdown.get(k, 0.0))
             for k, v in a.coll_breakdown.items()}
     return RooflineTerms(
+        chip=a.chip,
         flops=max(0.0, a.flops - b.flops),
         bytes_hbm=max(0.0, a.bytes_hbm - b.bytes_hbm),
         bytes_coll=max(0.0, a.bytes_coll - b.bytes_coll),

@@ -79,6 +79,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import hashlib
 import itertools
 import threading
@@ -89,8 +90,13 @@ from typing import Callable, Dict, List, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.distributed.sharding import axis_rules, param_shardings
+from repro.distributed.sharding import (
+    axis_rules,
+    cache_shardings,
+    param_shardings,
+)
 from repro.serve.kvpool import KVPool
 from repro.serve.prefix import EncoderCache, PrefixTrie
 from repro.serve.sampling import SamplingParams, sample_logits_batch
@@ -209,17 +215,29 @@ class PreemptedState:
     enc_len: int = 0                    # valid encoder rows behind xpages
 
 
-def _tick_fns(model):
-    """The jitted serving entry points for ``model``, built once and
-    cached ON the model object: every engine over the same model (replica
-    pools, re-created engines, the test matrix's chunk-size sweeps) reuses
-    one trace cache instead of recompiling per engine. The functions close
-    over nothing but the model; batch width, chunk width, page-table
-    width, and — under a mesh — input shardings are ordinary retrace
-    keys."""
-    cached = getattr(model, "_serve_tick_fns", None)
-    if cached is not None:
-        return cached
+def _tick_fns(model, shardings=None):
+    """The jitted serving entry points for ``model``, built once per
+    ``shardings`` and cached ON the model object: every engine over the
+    same model (replica pools, re-created engines, the test matrix's
+    chunk-size sweeps) reuses one trace cache instead of recompiling per
+    engine. The functions close over nothing but the model; batch width,
+    chunk width, page-table width and input shardings are ordinary
+    retrace keys.
+
+    ``shardings`` (mesh engines) is ``(slot, caches)``: the sharding of
+    the per-slot state vectors and the per-leaf tree of cache shardings
+    the engine places its state with. Every tick output is pinned to
+    them: a tick's outputs are the next tick's inputs, so a layout left to
+    the compiler would differ from the one the AOT executables were
+    compiled for."""
+    slot_sh = cache_sh = key = None
+    if shardings is not None:
+        slot_sh, cache_sh = shardings
+        leaves, treedef = jax.tree_util.tree_flatten(cache_sh)
+        key = (slot_sh, tuple(leaves), treedef)
+    cached = getattr(model, "_serve_tick_fns", {})
+    if key in cached:
+        return cached[key]
     cross = getattr(model, "has_cross_attn", False)
 
     def _row_keys(base_keys, counts):
@@ -289,9 +307,12 @@ def _tick_fns(model):
         TRACE_COUNTS["restore_slot"] += 1
         return model.restore_slot_caches(caches, slot, snaps)
 
-    fns = (jax.jit(_decode_tick), jax.jit(_extend_tick),
-           jax.jit(_reset_slot), jax.jit(_snapshot_slot),
-           jax.jit(_restore_slot))
+    tick_sh = None if shardings is None else (slot_sh, cache_sh, slot_sh)
+    fns = (jax.jit(_decode_tick, out_shardings=tick_sh),
+           jax.jit(_extend_tick, out_shardings=tick_sh),
+           jax.jit(_reset_slot, out_shardings=cache_sh),
+           jax.jit(_snapshot_slot, out_shardings=slot_sh),
+           jax.jit(_restore_slot, out_shardings=cache_sh))
     if cross:
         def _encode_tick(params, frames, valid, caches, xptab):
             """ENCODE phase: one padded batch=1 encoder pass + the cross
@@ -305,8 +326,8 @@ def _tick_fns(model):
             return model.write_cross(
                 params, memory, caches, xptab, positions, valid)
 
-        fns = fns + (jax.jit(_encode_tick),)
-    model._serve_tick_fns = fns
+        fns = fns + (jax.jit(_encode_tick, out_shardings=cache_sh),)
+    model._serve_tick_fns = {**cached, key: fns}
     return fns
 
 
@@ -622,8 +643,8 @@ class BatchedEngine:
         cache_kw = {}
         if self._cross:
             cache_kw["cross_pages"] = self.xpool.n_pages
-        self.caches = model.init_caches(
-            cfg.n_slots, cfg.max_len, cache_dtype,
+        init_caches = functools.partial(
+            model.init_caches, cfg.n_slots, cfg.max_len, cache_dtype,
             page_tokens=self.pt if self._paged else None,
             n_pages=n_pages if self._paged else None,
             **cache_kw,
@@ -641,8 +662,25 @@ class BatchedEngine:
         # samples with fold_in(request_key, t), independent of scheduling
         self._slot_keys = jnp.zeros((cfg.n_slots, 2), jnp.uint32)
         self._counts = np.zeros((cfg.n_slots,), np.int64)
+        shardings = None
+        if mesh is None:
+            self.caches = init_caches()
+        else:
+            # Under a mesh the caches come up in place — KV heads over the
+            # model axis, 1/TP of the pool per chip (cache_shardings) —
+            # and the per-slot vectors, a few bytes a slot, replicated on
+            # every chip; left on the default device they would all land
+            # on the first chip. _tick_fns keeps every tick output so.
+            cache_sh = cache_shardings(mesh, jax.eval_shape(init_caches))
+            self.caches = jax.jit(init_caches, out_shardings=cache_sh)()
+            slot_sh = NamedSharding(mesh, P())
+            (self.lengths, self.tokens, self.temps, self.topks,
+             self._slot_keys) = jax.device_put(
+                (self.lengths, self.tokens, self.temps, self.topks,
+                 self._slot_keys), slot_sh)
+            shardings = (slot_sh, cache_sh)
 
-        fns = _tick_fns(model)
+        fns = _tick_fns(model, shardings)
         (self._decode, self._extend, self._reset,
          self._snapshot, self._restore) = fns[:5]
         self._encode = fns[5] if len(fns) > 5 else None

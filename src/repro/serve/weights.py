@@ -249,17 +249,12 @@ def tile_serving_bytes(params) -> int:
     )
 
 
-def per_device_tile_bytes(params) -> Dict[str, int]:
-    """device -> bytes of packed tile bits RESIDENT on that device.
-
-    For a mesh-placed param tree this is what each chip's HBM actually
-    holds: sharded tiles count 1/TP of their bytes per device, replicated
-    leaves count fully on every device. Unplaced (single-device) trees
-    report one entry. The tensor-parallel acceptance check is that the
-    per-device number scales as 1/TP of ``tile_serving_bytes``.
-    """
+def per_device_bytes(leaves) -> Dict[str, int]:
+    """device -> bytes of ``leaves`` RESIDENT on that device: a sharded
+    leaf counts each shard on its own device, a replicated one fully on
+    every device, an unplaced (host) one under one entry."""
     out: Dict[str, int] = {}
-    for _, leaf in _tile_leaves(params):
+    for leaf in leaves:
         shards = getattr(leaf, "addressable_shards", None)
         if not shards:
             dev = str(getattr(leaf, "device", "host"))
@@ -267,6 +262,17 @@ def per_device_tile_bytes(params) -> Dict[str, int]:
             continue
         for sh in shards:
             dev = str(sh.device)
-            out[dev] = out.get(dev, 0) + int(np.prod(sh.data.shape)) \
-                * jnp.dtype(sh.data.dtype).itemsize
+            out[dev] = out.get(dev, 0) + sh.data.nbytes
     return out
+
+
+def per_device_tile_bytes(params) -> Dict[str, int]:
+    """device -> bytes of packed tile bits RESIDENT on that device.
+
+    For a mesh-placed param tree this is what each chip's HBM actually
+    holds: sharded tiles count 1/TP of their bytes per device, replicated
+    leaves count fully on every device. The tensor-parallel acceptance
+    check is that the per-device number scales as 1/TP of
+    ``tile_serving_bytes``.
+    """
+    return per_device_bytes(leaf for _, leaf in _tile_leaves(params))

@@ -196,7 +196,7 @@ class TestShardedServe:
         axis) reproduces the single-device logits through prefill AND
         decode, and each device holds exactly 1/TP of the tile bytes."""
         run_subprocess("""
-        from repro.compat import make_auto_mesh
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs import build_model, get_config
         from repro.distributed.sharding import axis_rules, param_shardings
         from repro.nn import module as mod
@@ -258,7 +258,7 @@ class TestShardedServe:
         r/TP) and matches the dense reconstruction oracle at m in
         {1, 3, 8} on a 4-way model mesh."""
         run_subprocess("""
-        from repro.compat import make_auto_mesh
+        from repro.launch.mesh import make_auto_mesh
         from repro.core.packing import pack_bits
         from repro.core.tiling import plan_tiling
         from repro.distributed.sharding import axis_rules
@@ -292,7 +292,7 @@ class TestShardedServe:
         (explicit greedy / temperature / top-k requests over a stochastic
         engine default) generates identical tokens single-device vs TP=4."""
         run_subprocess("""
-        from repro.compat import make_auto_mesh
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs import build_model, get_config
         from repro.nn import module as mod
         from repro.nn.context import SERVE, TRAIN, ModelContext
@@ -336,7 +336,7 @@ class TestShardedServe:
         """BatchedEngine(mesh=...) generates the same tokens as the
         single-device engine for a batch of concurrent requests."""
         run_subprocess("""
-        from repro.compat import make_auto_mesh
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs import build_model, get_config
         from repro.nn import module as mod
         from repro.nn.context import SERVE, TRAIN, ModelContext
@@ -368,6 +368,57 @@ class TestShardedServe:
             eng.run_until_drained()
             outs[name] = [r.output for r in reqs]
         assert outs["single"] == outs["tp"], outs
+        print("PASS")
+        """)
+
+    def test_engine_mesh_splits_kv_pool_over_model(self):
+        """On a (2, 2) data x model mesh every chip holds exactly half of
+        the KV pool (KV heads over model, pages replicated over data),
+        before and after a drain, and tokens match the single device."""
+        run_subprocess("""
+        import dataclasses
+        from repro.launch.mesh import make_auto_mesh
+        from repro.configs import build_model, get_config
+        from repro.nn import module as mod
+        from repro.nn.context import SERVE, TRAIN, ModelContext
+        from repro.serve.engine import BatchedEngine, ServeConfig
+        from repro.serve.sampling import SamplingParams
+        from repro.serve.weights import (
+            export_serving_params, per_device_bytes)
+
+        cfg = dataclasses.replace(get_config("granite-8b").reduced(),
+                                  n_kv=4)
+        tm = build_model(cfg, ModelContext(policy=cfg.tbn, mode=TRAIN,
+                                           compute_dtype=jnp.float32))
+        sm = build_model(cfg, ModelContext(policy=cfg.tbn, mode=SERVE,
+                                           compute_dtype=jnp.float32,
+                                           use_pallas=False))
+        tp0 = mod.init_params(tm.specs(), jax.random.PRNGKey(0))
+        sp = export_serving_params(tm.specs(), sm.specs(), tp0, cfg.tbn)
+
+        outs = {}
+        for name, mesh in [
+            ("single", None),
+            ("mesh", make_auto_mesh((2, 2), ("data", "model"))),
+        ]:
+            eng = BatchedEngine(
+                sm, sp,
+                ServeConfig(n_slots=2, max_len=64, chunk_tokens=8),
+                mesh=mesh,
+            )
+            total = sum(l.nbytes for l in jax.tree.leaves(eng.caches))
+            if mesh is not None:
+                eng.warmup()
+                before = per_device_bytes(jax.tree.leaves(eng.caches))
+                assert len(before) == 4, before
+                assert all(2 * b == total for b in before.values()), before
+            reqs = [eng.submit(p, SamplingParams(max_tokens=4))
+                    for p in ([1, 2, 3], [4, 5])]
+            eng.run_until_drained()
+            outs[name] = [r.output for r in reqs]
+            if mesh is not None:
+                assert per_device_bytes(jax.tree.leaves(eng.caches)) == before
+        assert outs["single"] == outs["mesh"], outs
         print("PASS")
         """)
 
