@@ -1,9 +1,9 @@
 """Telemetry overhead guard: decode tick latency with telemetry on vs off.
 
 The telemetry layer is allowed on the tick thread only because it is
-cheap — a handful of ``perf_counter`` reads, one ``bisect`` per
-histogram observe, and ``block_until_ready`` fences the tick loop was
-already paying implicitly at the host sync. This bench measures that
+cheap — a ``perf_counter`` read and one ``bisect`` per tick phase, and
+profiler spans that cost nothing unless a trace is being taken. This
+bench measures that
 claim instead of asserting it in a comment: two FRESH engines (jit
 caches never shared), identical stochastic request batches, alternating
 measurement rounds so neither variant systematically rides a warmer

@@ -301,7 +301,8 @@ class EncDecModel:
         ``cross_page_table`` and masked by ``enc_lens``."""
         if page_table is None:
             return self._decode_step_dense(params, tokens, caches, lengths)
-        x = self.embed(params["embed"], tokens)
+        with jax.named_scope("embed"):
+            x = self.embed(params["embed"], tokens)
         x, caches = self._walk_dec_paged(
             params, x, caches,
             lambda blk, pl, h, kl, vl, xk, xv: self._paged_layer(
@@ -312,8 +313,9 @@ class EncDecModel:
                 ),
             ),
         )
-        h = self.dec_norm(params["dec_norm"], x)
-        logits = self.head(params["head"], h)
+        with jax.named_scope("head"):
+            h = self.dec_norm(params["dec_norm"], x)
+            logits = self.head(params["head"], h)
         return logits[:, 0], caches, lengths + 1
 
     def _decode_step_dense(self, params, tokens, caches, lengths):
@@ -432,14 +434,16 @@ class EncDecModel:
         """One decoder layer against pool caches: self-attn (via
         ``self_step``, which closes over decode vs extend), read-only
         cross-attend, FFN — same residual order as DecBlock.__call__."""
-        a = blk.norm1(pl["norm1"], h)
-        a, kl, vl = self_step(a)
-        h = h + a
-        a = blk.norm2(pl["norm2"], h)
-        h = h + blk.cross_attn.cross_attend(
-            pl["cross_attn"], a, xk_l, xv_l, enc_lens, page_table=xptab,
-        )
-        h = h + blk.ffn(pl["ffn"], blk.norm3(pl["norm3"], h))
+        with jax.named_scope("attn"):
+            a = blk.norm1(pl["norm1"], h)
+            a, kl, vl = self_step(a)
+            h = h + a
+            a = blk.norm2(pl["norm2"], h)
+            h = h + blk.cross_attn.cross_attend(
+                pl["cross_attn"], a, xk_l, xv_l, enc_lens, page_table=xptab,
+            )
+        with jax.named_scope("ffn"):
+            h = h + blk.ffn(pl["ffn"], blk.norm3(pl["norm3"], h))
         return h, kl, vl
 
     def _walk_dec_paged(self, params, x, caches, step_fn):
@@ -468,18 +472,20 @@ class EncDecModel:
             def body(carry, layer_xs):
                 h, kfull, vfull, idx = carry
                 pl, xk_l, xv_l = layer_xs
-                kl = jax.lax.dynamic_index_in_dim(kfull, idx, 0,
-                                                  keepdims=False)
-                vl = jax.lax.dynamic_index_in_dim(vfull, idx, 0,
-                                                  keepdims=False)
-                # barrier: stop LICM materializing converted copies of the
-                # whole stacked pool (see lm.py._walk_segments)
-                kl, vl = jax.lax.optimization_barrier((kl, vl))
+                with jax.named_scope("kv_layer"):
+                    kl = jax.lax.dynamic_index_in_dim(kfull, idx, 0,
+                                                      keepdims=False)
+                    vl = jax.lax.dynamic_index_in_dim(vfull, idx, 0,
+                                                      keepdims=False)
+                    # barrier: stop LICM materializing converted copies of
+                    # the whole stacked pool (see lm.py._walk_segments)
+                    kl, vl = jax.lax.optimization_barrier((kl, vl))
                 h, kl, vl = run_layer(pl, h, kl, vl, xk_l, xv_l)
-                kfull = jax.lax.dynamic_update_index_in_dim(
-                    kfull, kl.astype(kfull.dtype), idx, 0)
-                vfull = jax.lax.dynamic_update_index_in_dim(
-                    vfull, vl.astype(vfull.dtype), idx, 0)
+                with jax.named_scope("kv_layer"):
+                    kfull = jax.lax.dynamic_update_index_in_dim(
+                        kfull, kl.astype(kfull.dtype), idx, 0)
+                    vfull = jax.lax.dynamic_update_index_in_dim(
+                        vfull, vl.astype(vfull.dtype), idx, 0)
                 return (h, kfull, vfull, idx + 1), None
 
             (x, ks, vs, _), _ = jax.lax.scan(
@@ -499,7 +505,8 @@ class EncDecModel:
         b, c = tokens.shape
         positions = lengths[:, None] + jnp.arange(c)[None, :]
         valid = jnp.arange(c)[None, :] < n_new[:, None]
-        x = self.embed(params["embed"], tokens)
+        with jax.named_scope("embed"):
+            x = self.embed(params["embed"], tokens)
         x, caches = self._walk_dec_paged(
             params, x, caches,
             lambda blk, pl, h, kl, vl, xk, xv: self._paged_layer(
@@ -510,10 +517,11 @@ class EncDecModel:
                 ),
             ),
         )
-        idx = jnp.clip(n_new - 1, 0, c - 1)
-        h_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-        h = self.dec_norm(params["dec_norm"], h_last)
-        logits = self.head(params["head"], h)
+        with jax.named_scope("head"):
+            idx = jnp.clip(n_new - 1, 0, c - 1)
+            h_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+            h = self.dec_norm(params["dec_norm"], h_last)
+            logits = self.head(params["head"], h)
         return logits[:, 0], caches, lengths + n_new
 
     # ---- per-slot cache walkers: everything is paged, so these are ----

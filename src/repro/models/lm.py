@@ -183,14 +183,7 @@ class Block:
             h_out = self.mixer(params["mixer"], h)
             cache = self._rec_final_state(params["mixer"], h)
             h = h_out
-        x = x + h
-        if self.has_ffn:
-            h = self.norm2(params["norm2"], x)
-            if self.use_moe:
-                h, _ = self.ffn(params["ffn"], h)
-            else:
-                h = self.ffn(params["ffn"], h)
-            x = x + h
+        x = self._ffn(params, x + h)
         x = logical_constraint(x, "act_batch", "act_res_seq", "act_embed")
         return x, cache
 
@@ -211,36 +204,44 @@ class Block:
 
     def decode_step(self, params, x, cache, *, lengths,
                     page_table=None, active=None):
-        h = self.norm1(params["norm1"], x)
-        if self.kind == "attn":
-            window = self.cfg.window
-            if window:
-                # ring-buffer positions within the bounded window cache
-                slot = lengths % cache["k"].shape[1]
-                h, ck, cv = self._windowed_decode(params["mixer"], h, cache, lengths, slot)
-                cache = {"k": ck, "v": cv}
-            elif "ks" in cache:
-                h, cache = self.mixer.decode_step_quant(
-                    params["mixer"], h, cache, lengths,
-                    page_table=page_table, active=active,
-                )
+        with jax.named_scope(self.kind):
+            h = self.norm1(params["norm1"], x)
+            if self.kind == "attn":
+                window = self.cfg.window
+                if window:
+                    # ring-buffer positions within the bounded window cache
+                    slot = lengths % cache["k"].shape[1]
+                    h, ck, cv = self._windowed_decode(
+                        params["mixer"], h, cache, lengths, slot)
+                    cache = {"k": ck, "v": cv}
+                elif "ks" in cache:
+                    h, cache = self.mixer.decode_step_quant(
+                        params["mixer"], h, cache, lengths,
+                        page_table=page_table, active=active,
+                    )
+                else:
+                    h, ck, cv = self.mixer.decode_step(
+                        params["mixer"], h, cache["k"], cache["v"], lengths,
+                        page_table=page_table, active=active,
+                    )
+                    cache = {"k": ck, "v": cv}
             else:
-                h, ck, cv = self.mixer.decode_step(
-                    params["mixer"], h, cache["k"], cache["v"], lengths,
-                    page_table=page_table, active=active,
-                )
-                cache = {"k": ck, "v": cv}
-        else:
-            h, cache = self.mixer.decode_step(params["mixer"], h, cache)
-        x = x + h
-        if self.has_ffn:
+                h, cache = self.mixer.decode_step(params["mixer"], h, cache)
+            x = x + h
+        return self._ffn(params, x), cache
+
+    def _ffn(self, params, x):
+        """The serving paths' second half of a block: norm2 + FFN/MoE and
+        its residual, under the ``ffn`` scope."""
+        if not self.has_ffn:
+            return x
+        with jax.named_scope("ffn"):
             h = self.norm2(params["norm2"], x)
             if self.use_moe:
                 h, _ = self.ffn(params["ffn"], h)
             else:
                 h = self.ffn(params["ffn"], h)
-            x = x + h
-        return x, cache
+            return x + h
 
     def extend(self, params, x, cache, *, positions, valid, page_table=None):
         """Advance a (B, C) column block at per-slot offsets against the
@@ -251,34 +252,29 @@ class Block:
         untouched up to dtype). ``page_table`` routes full-attention K/V
         writes through the paged pool.
         """
-        h = self.norm1(params["norm1"], x)
-        if self.kind == "attn":
-            if self.cfg.window:
-                h, cache = self._windowed_extend(
-                    params["mixer"], h, cache, positions, valid
-                )
-            elif "ks" in cache:
-                h, cache = self.mixer.extend_quant(
-                    params["mixer"], h, cache, positions, valid,
-                    page_table=page_table,
-                )
+        with jax.named_scope(self.kind):
+            h = self.norm1(params["norm1"], x)
+            if self.kind == "attn":
+                if self.cfg.window:
+                    h, cache = self._windowed_extend(
+                        params["mixer"], h, cache, positions, valid
+                    )
+                elif "ks" in cache:
+                    h, cache = self.mixer.extend_quant(
+                        params["mixer"], h, cache, positions, valid,
+                        page_table=page_table,
+                    )
+                else:
+                    h, ck, cv = self.mixer.extend(
+                        params["mixer"], h, cache["k"], cache["v"],
+                        positions, valid, page_table=page_table,
+                    )
+                    cache = {"k": ck, "v": cv}
             else:
-                h, ck, cv = self.mixer.extend(
-                    params["mixer"], h, cache["k"], cache["v"], positions,
-                    valid, page_table=page_table,
-                )
-                cache = {"k": ck, "v": cv}
-        else:
-            h, cache = self.mixer.extend(params["mixer"], h, cache, valid)
-        x = x + h
-        if self.has_ffn:
-            h = self.norm2(params["norm2"], x)
-            if self.use_moe:
-                h, _ = self.ffn(params["ffn"], h)
-            else:
-                h = self.ffn(params["ffn"], h)
+                h, cache = self.mixer.extend(params["mixer"], h, cache,
+                                             valid)
             x = x + h
-        return x, cache
+        return self._ffn(params, x), cache
 
     def _windowed_extend(self, params, x, cache, positions, valid):
         """Chunked prefill against the sliding-window ring cache.
@@ -771,31 +767,34 @@ class DecoderLM:
             else:
                 def body(carry, pl):
                     h, full, idx = carry
-                    cl = jax.tree.map(
-                        lambda v: jax.lax.dynamic_index_in_dim(
-                            v, idx, 0, keepdims=False
-                        ),
-                        full,
-                    )
-                    # Barrier: stops XLA hoisting per-layer cache converts
-                    # out of the loop (LICM would materialize an f32 copy
-                    # of the ENTIRE stacked KV cache). int8 codes cannot
-                    # be promoted, so only float cache leaves need it
-                    # (§Perf iteration B3: neutral, kept for clarity).
-                    needs_barrier = any(
-                        jnp.issubdtype(v.dtype, jnp.floating)
-                        for v in jax.tree_util.tree_leaves(cl)
-                        if v.ndim >= 4
-                    )
-                    if needs_barrier:
-                        cl = jax.lax.optimization_barrier(cl)
+                    with jax.named_scope("kv_layer"):
+                        cl = jax.tree.map(
+                            lambda v: jax.lax.dynamic_index_in_dim(
+                                v, idx, 0, keepdims=False
+                            ),
+                            full,
+                        )
+                        # Barrier: stops XLA hoisting per-layer cache
+                        # converts out of the loop (LICM would materialize
+                        # an f32 copy of the ENTIRE stacked KV cache). int8
+                        # codes cannot be promoted, so only float cache
+                        # leaves need it (§Perf iteration B3: neutral, kept
+                        # for clarity).
+                        needs_barrier = any(
+                            jnp.issubdtype(v.dtype, jnp.floating)
+                            for v in jax.tree_util.tree_leaves(cl)
+                            if v.ndim >= 4
+                        )
+                        if needs_barrier:
+                            cl = jax.lax.optimization_barrier(cl)
                     h2, c2 = step_fn(seg.block, pl, h, cl)
-                    full = jax.tree.map(
-                        lambda v, n: jax.lax.dynamic_update_index_in_dim(
-                            v, n.astype(v.dtype), idx, 0
-                        ),
-                        full, c2,
-                    )
+                    with jax.named_scope("kv_layer"):
+                        full = jax.tree.map(
+                            lambda v, n: jax.lax.dynamic_update_index_in_dim(
+                                v, n.astype(v.dtype), idx, 0
+                            ),
+                            full, c2,
+                        )
                     return (h2, full, idx + 1), None
 
                 (x, cache, _), _ = jax.lax.scan(
@@ -812,7 +811,8 @@ class DecoderLM:
         paged pool; ``active`` (B,) confines those pool writes to live
         decoding slots (per-slot families are confined by the engine's
         merge instead)."""
-        x = self.embed(params["embed"], tokens)
+        with jax.named_scope("embed"):
+            x = self.embed(params["embed"], tokens)
         x, new_caches = self._walk_segments(
             params, x, caches,
             lambda blk, pl, h, cl: blk.decode_step(
@@ -820,8 +820,9 @@ class DecoderLM:
                 page_table=page_table, active=active,
             ),
         )
-        h = self.final_norm(params["final_norm"], x)
-        logits = self.logits(params, h)
+        with jax.named_scope("head"):
+            h = self.final_norm(params["final_norm"], x)
+            logits = self.logits(params, h)
         return logits[:, 0], new_caches, lengths + 1
 
     def extend(self, params, tokens, caches, lengths, n_new,
@@ -840,7 +841,8 @@ class DecoderLM:
         b, c = tokens.shape
         positions = lengths[:, None] + jnp.arange(c)[None, :]
         valid = jnp.arange(c)[None, :] < n_new[:, None]
-        x = self.embed(params["embed"], tokens)
+        with jax.named_scope("embed"):
+            x = self.embed(params["embed"], tokens)
         x, new_caches = self._walk_segments(
             params, x, caches,
             lambda blk, pl, h, cl: blk.extend(
@@ -848,10 +850,11 @@ class DecoderLM:
                 page_table=page_table,
             ),
         )
-        idx = jnp.clip(n_new - 1, 0, c - 1)
-        h_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-        h = self.final_norm(params["final_norm"], h_last)
-        logits = self.logits(params, h)
+        with jax.named_scope("head"):
+            idx = jnp.clip(n_new - 1, 0, c - 1)
+            h_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+            h = self.final_norm(params["final_norm"], h_last)
+            logits = self.logits(params, h)
         return logits[:, 0], new_caches, lengths + n_new
 
     def merge_caches(self, old, new, keep, paged: bool = False):

@@ -103,6 +103,9 @@ from repro.serve.sampling import SamplingParams, sample_logits_batch
 from repro.serve.servable import ensure_servable
 from repro.serve.telemetry import PARKED, EngineTelemetry, RequestSpan
 
+# what a tick phase is with telemetry off: no span, no clock read
+_UNTIMED = contextlib.nullcontext()
+
 PREFILL = "prefill"
 DECODE = "decode"
 # Encoder-decoder models only: the phase between admission and PREFILL in
@@ -265,13 +268,16 @@ def _tick_fns(model, shardings=None):
             params, tokens, caches, lengths,
             page_table=ptab, active=active, **_extra_kw(extra),
         )
-        nxt = sample_logits_batch(
-            logits, _row_keys(base_keys, counts),
-            temperature=temps, top_k=topks,
-        )
-        caches = model.merge_caches(caches, new_caches, active, paged=True)
-        lengths = jnp.where(active, new_lengths, lengths)
-        nxt = jnp.where(active, nxt, tokens[:, 0])
+        with jax.named_scope("sample"):
+            nxt = sample_logits_batch(
+                logits, _row_keys(base_keys, counts),
+                temperature=temps, top_k=topks,
+            )
+        with jax.named_scope("merge"):
+            caches = model.merge_caches(caches, new_caches, active,
+                                        paged=True)
+            lengths = jnp.where(active, new_lengths, lengths)
+            nxt = jnp.where(active, nxt, tokens[:, 0])
         return nxt, caches, lengths
 
     def _extend_tick(params, block, caches, lengths, n_new,
@@ -284,10 +290,11 @@ def _tick_fns(model, shardings=None):
             params, block, caches, lengths, n_new, page_table=ptab,
             **_extra_kw(extra),
         )
-        toks = sample_logits_batch(
-            logits, _row_keys(base_keys, counts),
-            temperature=temps, top_k=topks,
-        )
+        with jax.named_scope("sample"):
+            toks = sample_logits_batch(
+                logits, _row_keys(base_keys, counts),
+                temperature=temps, top_k=topks,
+            )
         return toks, caches, lengths
 
     def _reset_slot(caches, slot):
@@ -698,7 +705,6 @@ class BatchedEngine:
             EngineTelemetry(trace_events=cfg.trace_events).bind_engine(self)
             if cfg.telemetry else None
         )
-        self._tick_phases: Dict[str, float] = {}
         # Retrace detector: armed by warmup(). Compares the global
         # TRACE_COUNTS sum across ONE tick (this thread runs the whole
         # tick, so any delta is attributable to this engine's tick fns),
@@ -1531,119 +1537,137 @@ class BatchedEngine:
             budget -= take
         return takes
 
+    def _timed(self, phase: str, rows: int = 0):
+        """Tick phase ``phase`` (``telemetry.TICK_PHASES``) as a profiler
+        span and a histogram observation; nothing at all with telemetry
+        off."""
+        if self.tel is None:
+            return _UNTIMED
+        return self.tel.phase(phase, self.steps, rows)
+
     def _run_extend(self, takes: Dict[int, int]):
         cfg = self.cfg
         tel = self.tel
-        block = np.zeros((cfg.n_slots, cfg.chunk_tokens), np.int32)
-        n_new = np.zeros((cfg.n_slots,), np.int32)
-        for slot, take in takes.items():
-            off = int(self._offsets[slot])
-            block[slot, :take] = self._live[slot].prompt[off:off + take]
-            n_new[slot] = take
-            self._ensure_pages(slot, off + take - 1)
-        t0 = time.perf_counter() if tel is not None else 0.0
-        toks, self.caches, self.lengths = self._aot.get(
-            "extend_tick", self._extend)(
-            self.params, jnp.asarray(block), self.caches, self.lengths,
-            jnp.asarray(n_new), self.temps, self.topks,
-            self._slot_keys, jnp.asarray(self._counts),
-            jnp.asarray(self._ptab), *self._cross_extra(),
-        )
+        rows = sum(takes.values())
+        with self._timed("extend.prepare", rows):
+            block = np.zeros((cfg.n_slots, cfg.chunk_tokens), np.int32)
+            n_new = np.zeros((cfg.n_slots,), np.int32)
+            for slot, take in takes.items():
+                off = int(self._offsets[slot])
+                block[slot, :take] = self._live[slot].prompt[off:off + take]
+                n_new[slot] = take
+                self._ensure_pages(slot, off + take - 1)
+            block, n_new = jnp.asarray(block), jnp.asarray(n_new)
+            counts, ptab = jnp.asarray(self._counts), jnp.asarray(self._ptab)
+            extra = self._cross_extra()
+        with self._timed("extend.launch", rows):
+            toks, self.caches, self.lengths = self._aot.get(
+                "extend_tick", self._extend)(
+                self.params, block, self.caches, self.lengths, n_new,
+                self.temps, self.topks, self._slot_keys, counts, ptab,
+                *extra,
+            )
+        with self._timed("extend.wait", rows):
+            # the tick's fence: the pull returns once the device is done
+            toks_host = np.asarray(toks)
+        with self._timed("extend.emit", rows):
+            if tel is not None:
+                tel.prefill_tokens.inc(rows)
+            for slot, take in takes.items():
+                self._emit_extend(slot, take, toks_host)
+            # freeing a device array can wait for the GIL: do it in a phase
+            del toks, block, n_new, counts, ptab, extra
+
+    def _emit_extend(self, slot: int, take: int, toks_host: np.ndarray):
+        """Advance one slot past its extend chunk; a completed prompt
+        emits its first token."""
+        tel = self.tel
+        req = self._live[slot]
+        self._offsets[slot] += take
+        off_new = int(self._offsets[slot])
+        if off_new in self._need_snaps[slot]:
+            # prefill just landed on a boundary the trie is missing:
+            # pin the recurrent state HERE so the published (or
+            # snapshot-backfilled) node can restore it
+            self._snaps[slot][off_new] = self._aot.get(
+                "snapshot_slot", self._snapshot)(self.caches, slot)
+        if self._offsets[slot] != len(req.prompt):
+            return
+        # prompt complete: the chunk's last-column logits are the
+        # request's first sampled token
+        self._phase[slot] = DECODE
+        self._admit_order.remove(slot)
+        tok = int(toks_host[slot])
+        req.output.append(tok)
+        req.token_steps.append(self.steps)
+        if len(req.output) == 1:
+            # first token: per-class TTFT in ticks, measured from
+            # submission (queue wait + parked time included)
+            acc = self._class_ttft.setdefault(req.priority, [0, 0])
+            acc[0] += self.steps - req.submit_step
+            acc[1] += 1
+        self._counts[slot] += 1
+        self._stats["tokens_out"] += 1
         if tel is not None:
-            # bound the device phase: async dispatch means the call above
-            # returned before the computation finished; waiting on the
-            # sampled tokens (needed on host immediately below anyway)
-            # splits device compute from host bookkeeping without
-            # changing any value
-            jax.block_until_ready(toks)
-            t1 = time.perf_counter()
-            self._tick_phases["prefill_device"] = t1 - t0
-            tel.prefill_tokens.inc(sum(takes.values()))
-        toks_host = np.asarray(toks)
-        for slot, take in takes.items():
-            req = self._live[slot]
-            self._offsets[slot] += take
-            off_new = int(self._offsets[slot])
-            if off_new in self._need_snaps[slot]:
-                # prefill just landed on a boundary the trie is missing:
-                # pin the recurrent state HERE so the published (or
-                # snapshot-backfilled) node can restore it
-                self._snaps[slot][off_new] = self._aot.get(
-                    "snapshot_slot", self._snapshot)(self.caches, slot)
-            if self._offsets[slot] == len(req.prompt):
-                # prompt complete: the chunk's last-column logits are the
-                # request's first sampled token
-                self._phase[slot] = DECODE
-                self._admit_order.remove(slot)
-                tok = int(toks_host[slot])
+            tel.tokens.inc()
+            if req.span is not None:
+                now = time.monotonic()
+                req.span.to_phase(DECODE, now)
+                if req.span.token(now):
+                    tel.ttft.observe(now - req.span.submit_t)
+        self.tokens = self.tokens.at[slot, 0].set(tok)
+        if self.on_token is not None:
+            self.on_token(req, tok)
+        self._maybe_retire(slot, req, tok)
+
+    def _run_decode(self, decoding: List[int]):
+        tel = self.tel
+        rows = len(decoding)
+        with self._timed("decode.prepare", rows):
+            active = np.zeros((self.cfg.n_slots,), bool)
+            active[decoding] = True
+            for slot in decoding:
+                req = self._live[slot]
+                # the row this step writes
+                pos = len(req.prompt) + len(req.output) - 1
+                if pos < self.cfg.max_len:
+                    self._ensure_pages(slot, pos)
+            active = jnp.asarray(active)
+            counts, ptab = jnp.asarray(self._counts), jnp.asarray(self._ptab)
+            extra = self._cross_extra()
+        with self._timed("decode.launch", rows):
+            nxt, self.caches, self.lengths = self._aot.get(
+                "decode_tick", self._decode)(
+                self.params, self.tokens, self.caches, self.lengths,
+                active, self.temps, self.topks, self._slot_keys, counts,
+                ptab, *extra,
+            )
+        with self._timed("decode.wait", rows):
+            # the tick's fence: the pull returns once the device is done
+            nxt_host = np.asarray(nxt)
+        with self._timed("decode.emit", rows):
+            self.tokens = nxt[:, None]
+            for slot in decoding:
+                req = self._live[slot]
+                tok = int(nxt_host[slot])
                 req.output.append(tok)
                 req.token_steps.append(self.steps)
-                if len(req.output) == 1:
-                    # first token: per-class TTFT in ticks, measured from
-                    # submission (queue wait + parked time included)
-                    acc = self._class_ttft.setdefault(req.priority, [0, 0])
-                    acc[0] += self.steps - req.submit_step
-                    acc[1] += 1
                 self._counts[slot] += 1
                 self._stats["tokens_out"] += 1
                 if tel is not None:
                     tel.tokens.inc()
                     if req.span is not None:
                         now = time.monotonic()
-                        req.span.to_phase(DECODE, now)
+                        prev = req.span.last_token_t
                         if req.span.token(now):
                             tel.ttft.observe(now - req.span.submit_t)
-                self.tokens = self.tokens.at[slot, 0].set(tok)
+                        else:
+                            tel.itl.observe(now - prev)
                 if self.on_token is not None:
                     self.on_token(req, tok)
                 self._maybe_retire(slot, req, tok)
-        if tel is not None:
-            self._tick_phases["prefill_host"] = time.perf_counter() - t1
-
-    def _run_decode(self, decoding: List[int]):
-        tel = self.tel
-        active = np.zeros((self.cfg.n_slots,), bool)
-        active[decoding] = True
-        for slot in decoding:
-            req = self._live[slot]
-            pos = len(req.prompt) + len(req.output) - 1  # row this step writes
-            if pos < self.cfg.max_len:
-                self._ensure_pages(slot, pos)
-        t0 = time.perf_counter() if tel is not None else 0.0
-        nxt, self.caches, self.lengths = self._aot.get(
-            "decode_tick", self._decode)(
-            self.params, self.tokens, self.caches, self.lengths,
-            jnp.asarray(active), self.temps, self.topks,
-            self._slot_keys, jnp.asarray(self._counts),
-            jnp.asarray(self._ptab), *self._cross_extra(),
-        )
-        if tel is not None:
-            jax.block_until_ready(nxt)
-            t1 = time.perf_counter()
-            self._tick_phases["decode_device"] = t1 - t0
-        nxt_host = np.asarray(nxt)
-        self.tokens = nxt[:, None]
-        for slot in decoding:
-            req = self._live[slot]
-            tok = int(nxt_host[slot])
-            req.output.append(tok)
-            req.token_steps.append(self.steps)
-            self._counts[slot] += 1
-            self._stats["tokens_out"] += 1
-            if tel is not None:
-                tel.tokens.inc()
-                if req.span is not None:
-                    now = time.monotonic()
-                    prev = req.span.last_token_t
-                    if req.span.token(now):
-                        tel.ttft.observe(now - req.span.submit_t)
-                    else:
-                        tel.itl.observe(now - prev)
-            if self.on_token is not None:
-                self.on_token(req, tok)
-            self._maybe_retire(slot, req, tok)
-        if tel is not None:
-            self._tick_phases["decode_host"] = time.perf_counter() - t1
+            # freeing a device array can wait for the GIL: do it in a phase
+            del nxt, active, counts, ptab, extra
 
     def step(self):
         """One engine tick: preemption pass + admissions/resumes +
@@ -1652,46 +1676,44 @@ class BatchedEngine:
         concurrent prefill (the fairness invariant); a prefilling slot
         emits its first token on the tick its final chunk lands. A slot
         preempted this tick emits nothing — exactly the cost the
-        preempt-free tick rate reports."""
+        preempt-free tick rate reports.
+
+        With telemetry on, the tick and each of its phases
+        (``telemetry.TICK_PHASES``) are profiler spans ``serve.tick`` /
+        ``serve.<phase>`` and observations of ``serve_tick_seconds`` /
+        ``serve_tick_phase_seconds``."""
+        if not self.has_work:
+            # an empty engine: nothing to schedule, nothing to observe (a
+            # poll loop must not drown the tick histograms in empty ticks)
+            return
         tel = self.tel
-        with self._mesh_ctx():
-            if tel is not None:
-                t_tick = time.perf_counter()
-                trace_pre = (sum(TRACE_COUNTS.values())
-                             if self._retrace_armed else 0)
+        trace_pre = (sum(TRACE_COUNTS.values())
+                     if tel is not None and self._retrace_armed else 0)
+        tick = _UNTIMED if tel is None else tel.tick_span(self.steps)
+        with self._mesh_ctx(), tick:
             if self.cfg.preempt:
-                self._preempt_pass()
-            if tel is not None:
-                t_adm = time.perf_counter()
-                self._tick_phases["preempt"] = t_adm - t_tick
-            self._admissions()
-            depth = self._queue.qsize()
-            if depth > self._stats["peak_queue_depth"]:
-                self._stats["peak_queue_depth"] = depth
-            if tel is not None:
-                self._tick_phases["admission"] = time.perf_counter() - t_adm
+                with self._timed("preempt"):
+                    self._preempt_pass()
+            with self._timed("admission"):
+                self._admissions()
+                depth = self._queue.qsize()
+                if depth > self._stats["peak_queue_depth"]:
+                    self._stats["peak_queue_depth"] = depth
             if not self._live:
-                # idle tick: no jitted call ran, nothing to observe (an
-                # empty-engine poll loop must not drown the tick
-                # histograms in zero-work samples)
-                self._tick_phases.clear()
                 return
             # ENCODE pass (cross models): warm-cache late hits resolve in
             # O(1); at most ONE padded encoder call actually runs per tick
             # and its cost is billed against the prefill budget below.
             enc_charge = 0
-            if self._cross:
-                t_enc = time.perf_counter() if tel is not None else 0.0
-                for s in list(self._admit_order):
-                    if self._phase[s] != ENCODE:
-                        continue
-                    if self._try_enc_cache(s, self._live[s]):
-                        continue
-                    enc_charge = self._run_encode(s)
-                    break
-                if tel is not None and enc_charge:
-                    self._tick_phases["encode"] = (
-                        time.perf_counter() - t_enc)
+            waiting = [s for s in self._admit_order
+                       if self._phase[s] == ENCODE] if self._cross else []
+            if waiting:
+                with self._timed("encode", len(waiting)):
+                    for s in waiting:
+                        if self._try_enc_cache(s, self._live[s]):
+                            continue
+                        enc_charge = self._run_encode(s)
+                        break
             decoding = [s for s in range(self.cfg.n_slots)
                         if self._phase[s] == DECODE]
             dec_reqs = [(self._live[s], len(self._live[s].output))
@@ -1712,18 +1734,14 @@ class BatchedEngine:
                     and all(len(r.output) == n + 1 for r, n in dec_reqs)):
                 self._stats["preempt_free_ticks"] += 1
             self._preempted_since_tick = False
-            if tel is not None:
-                self._observe_tick(t_tick, trace_pre)
+        if tel is not None:
+            self._check_retrace(trace_pre)
         self.steps += 1
 
-    def _observe_tick(self, t_tick: float, trace_pre: int):
-        """End-of-tick telemetry: the tick + per-phase histograms, then
-        the steady-state retrace check. Runs only with telemetry on."""
+    def _check_retrace(self, trace_pre: int):
+        """The steady-state retrace check, after every work tick with
+        telemetry on."""
         tel = self.tel
-        tel.tick.observe(time.perf_counter() - t_tick)
-        for phase, dt in self._tick_phases.items():
-            tel.tick_phase[phase].observe(dt)
-        self._tick_phases.clear()
         if not self._retrace_armed:
             return
         delta = sum(TRACE_COUNTS.values()) - trace_pre
@@ -1803,6 +1821,12 @@ class BatchedEngine:
             # /metrics exposes raw — absent entirely with telemetry off
             s["latency"] = self.tel.latency_summary()
             s["retraces"] = self.tel.retraces.get()
+            # exact cumulative seconds / counts of each tick phase and of
+            # whole work ticks (the histograms' sums), for window deltas
+            phases = self.tel.tick_phase.items()
+            s["tick_phase_s"] = {p: h.sum for p, h in phases}
+            s["tick_phase_n"] = {p: h.count for p, h in phases}
+            s["tick_s"] = self.tel.tick._solo().sum
         return s
 
     def run_until_drained(self, max_steps: int = 10_000, on_tick=None) -> int:

@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
 import dataclasses
 import json
 import queue
@@ -61,6 +62,8 @@ import socket
 import threading
 import time
 from typing import Callable, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.serve.detok import DetokenizeWorker, PieceCodec
 from repro.serve.engine import AdmissionQueueFull, BatchedEngine, Request
@@ -250,16 +253,22 @@ class EngineServer:
     # ---- engine thread ------------------------------------------------
     def _tick_loop(self):
         eng = self.engine
+        # the loop's time between ticks as profiler spans, serve.abort and
+        # serve.idle (with the engine's telemetry on only)
+        mark = (TraceAnnotation if eng.tel is not None
+                else lambda name: contextlib.nullcontext())
         while not self._stop:
-            while not self._abort_q.empty():
-                try:
-                    eng.abort(self._abort_q.get_nowait())
-                except queue.Empty:      # pragma: no cover
-                    break
+            if not self._abort_q.empty():
+                with mark("serve.abort"):
+                    while not self._abort_q.empty():
+                        try:
+                            eng.abort(self._abort_q.get_nowait())
+                        except queue.Empty:      # pragma: no cover
+                            break
             # has_work counts parked (preempted) requests too: a parked
             # stream with an empty queue still needs ticks to resume
             if not eng.has_work:
-                with self._wake:
+                with mark("serve.idle"), self._wake:
                     if self._stop:
                         return
                     self._wake.wait(0.05)

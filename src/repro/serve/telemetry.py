@@ -1,4 +1,5 @@
-"""Zero-dependency serving telemetry: metrics, spans, trace events.
+"""Serving telemetry, needing nothing beyond JAX: metrics, spans, trace
+events.
 
 The serving stack's only runtime visibility used to be the flat counter
 dict of ``BatchedEngine.stats()`` — fine for a drained batch, useless
@@ -23,6 +24,10 @@ observation layer (DESIGN.md §6.6):
 * :class:`EngineTelemetry` — the standard serving metric families plus
   the span/ring plumbing, bound to one engine (and extended in place by
   the HTTP front-end with its request/stream metrics).
+* :class:`Timed` — one engine tick, or one phase of it: a
+  ``jax.profiler.TraceAnnotation`` named ``serve.<phase>`` (free unless
+  a profiler trace is being taken) whose duration is observed, on exit,
+  into the matching histogram child.
 
 Telemetry is OBSERVATION ONLY: nothing here feeds back into scheduling
 or sampling, and emitted tokens are byte-identical with it on or off
@@ -48,6 +53,8 @@ import re
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
@@ -459,14 +466,39 @@ class TraceRing:
 # ---------------------------------------------------------------------
 # the serving metric families
 
-# tick phase vocabulary (DESIGN.md §6.6): admission bookkeeping, the
-# preempt/resume pass, the one-per-tick encoder call, and the prefill /
-# decode jitted calls split device-vs-host — "device" ends at
-# block_until_ready on the sampled tokens, "host" is the numpy pull +
-# python token/retirement loop after it.
-TICK_PHASES = ("admission", "preempt", "encode",
-               "prefill_device", "prefill_host",
-               "decode_device", "decode_host")
+# tick phase vocabulary (DESIGN.md §6.6), in tick order. They tile a work
+# tick: the preempt/resume pass, admission, the one-per-tick encoder pass,
+# then the extend and decode calls, each split four ways — ``prepare``
+# (input packing, page-table growth, host->device copies), ``launch`` (the
+# executable call until it returns: dispatch, not compute), ``wait`` (the
+# numpy pull of the sampled tokens, the tick's only fence: device compute
+# left after the launch plus the copy back) and ``emit`` (per-slot
+# bookkeeping, the on_token callbacks, retirement).
+TICK_PHASES = ("preempt", "admission", "encode",
+               "extend.prepare", "extend.launch", "extend.wait", "extend.emit",
+               "decode.prepare", "decode.launch", "decode.wait", "decode.emit")
+
+
+class Timed:
+    """A profiler span ``name`` (annotated with the engine tick and the
+    rows it works on) whose duration is observed into ``hist`` on exit.
+    Single-use: the engine makes one per tick or phase."""
+
+    __slots__ = ("_hist", "_span", "_t0")
+
+    def __init__(self, hist: Histogram, name: str, tick: int, rows: int):
+        self._hist = hist
+        self._span = TraceAnnotation(name, tick=tick, rows=rows)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._hist.observe(time.perf_counter() - self._t0)
+        self._span.__exit__(*exc)
+        return False
 
 
 class EngineTelemetry:
@@ -526,6 +558,15 @@ class EngineTelemetry:
         # lock or hashes label kwargs
         self.tick_phase = {p: tick_phase.labels(phase=p)
                            for p in TICK_PHASES}
+
+    def tick_span(self, tick: int) -> Timed:
+        """The whole tick: span ``serve.tick``, ``serve_tick_seconds``."""
+        return Timed(self.tick._solo(), "serve.tick", tick, 0)
+
+    def phase(self, name: str, tick: int, rows: int = 0) -> Timed:
+        """One tick phase of :data:`TICK_PHASES`: span ``serve.<name>``,
+        ``serve_tick_phase_seconds{phase=<name>}``."""
+        return Timed(self.tick_phase[name], f"serve.{name}", tick, rows)
 
     def bind_engine(self, engine):
         """Register the scrape-time gauges that read live engine state

@@ -14,6 +14,7 @@ fire, warn once) and a clean post-warmup run (must stay silent).
 import asyncio
 import functools
 import json
+import re
 import warnings
 
 import jax
@@ -238,21 +239,28 @@ class TestSpanAndRing:
 
 
 class TestEngineTelemetry:
-    def test_token_parity_on_vs_off(self):
+    def test_token_parity_on_vs_off(self, tmp_path):
         """The wall: byte-identical stochastic tokens with telemetry
-        (metrics + spans + ring) on vs off, prefix cache exercised."""
+        (metrics + spans + ring) off, on, and on under a running
+        profiler trace, prefix cache exercised."""
         rng = np.random.default_rng(2)
         prompts = [[int(t) for t in rng.integers(0, 64, size=n)]
                    for n in (5, 11, 7, 9)]
         params = [SamplingParams(max_tokens=6, temperature=0.9, top_k=8,
                                  seed=50 + i) for i in range(len(prompts))]
         outs = {}
-        for on in (False, True):
+        for mode in ("off", "on", "profiled"):
+            on = mode != "off"
             eng = make_engine(telemetry=on, prefix_cache=True,
                               trace_events=64 if on else 0)
-            outs[on] = drain(eng, [eng.submit(p, sp)
-                                   for p, sp in zip(prompts, params)])
-        assert outs[True] == outs[False]
+            reqs = [eng.submit(p, sp) for p, sp in zip(prompts, params)]
+            if mode == "profiled":
+                with jax.profiler.trace(str(tmp_path)):
+                    outs[mode] = drain(eng, reqs)
+            else:
+                outs[mode] = drain(eng, reqs)
+        assert outs["on"] == outs["off"]
+        assert outs["profiled"] == outs["off"]
 
     def test_lifecycle_metrics_and_spans(self):
         eng = make_engine(trace_events=64)
@@ -267,7 +275,10 @@ class TestEngineTelemetry:
         assert tel.itl._solo().count == 9    # 3 tokens after the first, each
         assert tel.tick._solo().count > 0
         observed = {p for p, h in tel.tick_phase.items() if h.count}
-        assert {"admission", "decode_device", "decode_host"} <= observed
+        assert {"admission", "extend.prepare", "extend.launch",
+                "extend.wait", "extend.emit", "decode.prepare",
+                "decode.launch", "decode.wait", "decode.emit"} <= observed
+        assert "preempt" not in observed     # preemption off: never run
         assert "encode" not in observed      # decoder-only: never charged
         # spans: disjoint phases cover [submit, finish] for every request
         for r in reqs:
@@ -338,17 +349,87 @@ class TestEngineTelemetry:
                    + r.value_of("serve_prefix_lookups_total", result="miss"))
         assert lookups == 2  # one trie lookup per admission
 
-    def test_telemetry_off_is_off(self):
+    def test_telemetry_off_is_off(self, monkeypatch):
         eng = make_engine(telemetry=False)
         assert eng.tel is None
         req = eng.submit([1, 2, 3], SamplingParams(max_tokens=2))
+        # no tick or tick phase opens a span or reads a clock: Timed, the
+        # one thing that does, is never made
+        from repro.serve import telemetry
+
+        def opened(*a, **k):
+            raise AssertionError("a tick phase was timed")
+        monkeypatch.setattr(telemetry, "Timed", opened)
         drain(eng, [req])
         assert req.span is None
-        assert "latency" not in eng.stats()
+        st = eng.stats()
+        assert "latency" not in st and "tick_phase_s" not in st
 
     def test_trace_events_requires_telemetry(self):
         with pytest.raises(ValueError):
             make_engine(telemetry=False, trace_events=16)
+
+
+class TestTickPhases:
+    """The tick phases (``telemetry.TICK_PHASES``): they tile each work
+    tick, reach the profiler as ``serve.*`` spans, and the model's named
+    scopes reach the compiled ticks."""
+
+    def test_phases_cover_the_tick_and_count_every_call(self):
+        eng = make_engine(n_slots=3, chunk_tokens=4)
+        calls = {"extend": 0, "decode": 0}
+        for kind in calls:
+            run = getattr(eng, f"_run_{kind}")
+
+            def counted(arg, run=run, kind=kind):
+                calls[kind] += 1
+                return run(arg)
+            setattr(eng, f"_run_{kind}", counted)
+        reqs = [eng.submit(list(range(1, n)), SamplingParams(max_tokens=5))
+                for n in (6, 13, 9, 3)]
+        drain(eng, reqs)
+        st = eng.stats()
+        assert set(st["tick_phase_s"]) == set(TICK_PHASES)
+        phases = sum(st["tick_phase_s"].values())
+        assert 0.95 * st["tick_s"] <= phases <= st["tick_s"]
+        assert st["tick_s"] == pytest.approx(eng.tel.tick._solo().sum)
+        n = st["tick_phase_n"]
+        assert n["admission"] == st["work_ticks"] == eng.tel.tick._solo().count
+        for kind in calls:
+            assert calls[kind] > 0
+            for part in ("prepare", "launch", "wait", "emit"):
+                assert n[f"{kind}.{part}"] == calls[kind], (kind, part)
+
+    def test_profiler_trace_holds_the_phase_spans(self, tmp_path):
+        import glob
+
+        from jax.profiler import ProfileData
+
+        eng = make_engine()
+        reqs = [eng.submit([1, 2, 3, 4], SamplingParams(max_tokens=3))]
+        with jax.profiler.trace(str(tmp_path)):
+            drain(eng, reqs)
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        names = {e.name for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events}
+        assert {"serve.tick", "serve.admission", "serve.extend.launch",
+                "serve.decode.prepare", "serve.decode.launch",
+                "serve.decode.wait", "serve.decode.emit"} <= names
+
+    def test_compiled_ticks_carry_the_model_scopes(self):
+        eng = make_engine()
+        eng.warmup()
+        for tick in ("decode_tick", "extend_tick"):
+            text = eng._aot[tick].as_text()
+            paths = re.findall(r'op_name="([^"]*)"', text)
+            scopes = {part for p in paths for part in p.split("/")}
+            assert {"embed", "attn", "ffn", "head", "kv_layer",
+                    "sample"} <= scopes, tick
+        assert "merge" in {part for p in re.findall(
+            r'op_name="([^"]*)"', eng._aot["decode_tick"].as_text())
+            for part in p.split("/")}
 
 
 class TestRetraceDetector:
