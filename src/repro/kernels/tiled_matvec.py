@@ -8,10 +8,15 @@ exist. Here the whole sublane-rounded batch IS the m block (no m grid
 axis, no m padding beyond the hardware sublane).
 
 Grid: (r/br, K/bk), k innermost (sequential accumulation), r parallel.
-VMEM per step: m·bk activations + br·bk/32 packed words + br·bk unpacked
-weights + m·br f32 accumulator, plus the u32 temporaries of the unpack —
-at the decode defaults (m<=32, br=128, bk=4096) inside the 16 MiB default
-scoped VMEM of a v5e core.
+The unpack is the matmul kernel's (``tiled_matmul._block_dot``): plane by
+plane, with the activations' columns in planar order (``planar_columns``),
+where the k block's words fill whole lane tiles — every call of a
+granite-8b decode tick, since bk = 4096 bits is 128 words — and the
+interleaved unpack for a narrower whole-row block. VMEM per step: m·bk
+activations + br·bk/32 packed words + m·br f32 accumulator + the unpack
+temporaries (br·bk/32 per plane, br·bk interleaved) — at the decode
+defaults (m<=32, br=128, bk=4096) inside the 16 MiB default scoped VMEM
+of a v5e core.
 
 Dispatch lives in ``ops.tiled_dense_infer``: any matmul with
 m <= MATVEC_MAX_M (after flattening lead dims; per-shard m under the
@@ -28,16 +33,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiled_matmul import LANE_BITS, WORD_BLOCK, _unpack_block
+from repro.kernels.tiled_matmul import (
+    LANE_BITS,
+    WORD_BLOCK,
+    _block_dot,
+    is_planar,
+    planar_columns,
+)
 
 # Dispatch threshold: batches at or under this m take the decode path.
 # 32 covers any realistic slot count while staying well inside the regime
 # where the matmul kernel's 128-row m blocks are mostly padding.
 MATVEC_MAX_M = 32
 # Decode blocking: one WORD_BLOCK of packed words (the Mosaic minimum
-# for a partial row, tiled_matmul.py) per k step. br=256 at that bk would
-# overrun the 16 MiB default scoped VMEM with the (br, bk) unpack
-# temporaries, so r blocks like the matmul kernel's.
+# for a partial row, tiled_matmul.py) per k step. br=256 at that bk
+# overran the 16 MiB default scoped VMEM with the interleaved unpack's
+# (br, bk) temporaries, so r blocks like the matmul kernel's.
 DECODE_BLOCK_R = 128
 DECODE_BLOCK_K = WORD_BLOCK * LANE_BITS
 
@@ -79,13 +90,7 @@ def _matvec_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int, compute_dtype):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bm, bk = x_ref.shape
-    br = w_ref.shape[0]
-    w = _unpack_block(w_ref[...], br, bk, compute_dtype)
-    x = x_ref[...].astype(compute_dtype)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += _block_dot(x_ref, w_ref, compute_dtype)
 
     @pl.when(ki == nk - 1)
     def _store():
@@ -104,9 +109,10 @@ def tiled_matvec_unique(
 ) -> jax.Array:
     """u = x @ T^T for a row-packed tile at decode-sized m.
 
-    x: (M, K) with M sublane-rounded (ops.py pads); packed: (r, K/32)
-    int32. Returns (M, r) in ``out_dtype``. M is one block — there is no
-    m grid axis; shapes must be pre-padded to block multiples on r/K.
+    x: (M, K) in natural column order, M sublane-rounded (ops.py pads);
+    packed: (r, K/32) int32. Returns (M, r) in ``out_dtype``. M is one
+    block — there is no m grid axis; shapes must be pre-padded to block
+    multiples on r/K.
     """
     m, k = x.shape
     if interpret is None:
@@ -119,6 +125,9 @@ def tiled_matvec_unique(
     assert block_k % LANE_BITS == 0
     nk = k // block_k
     compute_dtype = x.dtype if x.dtype in (jnp.bfloat16, jnp.float32) else jnp.float32
+
+    if is_planar(block_k):
+        x = planar_columns(x, block_k)
 
     kernel = functools.partial(_matvec_kernel, nk=nk, compute_dtype=compute_dtype)
     return pl.pallas_call(

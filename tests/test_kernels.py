@@ -151,6 +151,65 @@ def test_decode_matvec_kernel_direct(m):
                                rtol=1e-5, atol=1e-3)
 
 
+# --------------------------------------------------------------------------
+# planar unpack (k blocks of whole lane tiles) vs the interleaved unpack
+# --------------------------------------------------------------------------
+PLANAR_SHAPES = [
+    # (n_in, r): one 128-word block with r not a multiple of br; a
+    # 448-word row padded to four 128-word blocks (granite's down
+    # projection); a 47-word whole-row block, which unpacks interleaved
+    (4096, 200),
+    (14336, 72),
+    (1504, 136),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("n_in,r", PLANAR_SHAPES)
+@pytest.mark.parametrize("m", [1, 8, 16, 32, 128, 512])
+def test_tile_kernels_match_ref_across_unpack_orders(m, n_in, r, dtype):
+    """The serve dispatch at decode m (<= 32: matvec kernel) and extend m
+    (matmul kernel) against the row-packed oracle, on both unpack paths.
+    The operands are exact in both dtypes, so only the f32 accumulation
+    order differs from the oracle's."""
+    from repro.kernels.ops import _dense_unique_local
+    from repro.kernels.tiled_matmul import is_planar
+
+    words = -(-n_in // 32)
+    assert is_planar(min(4096, 32 * words)) == (words >= 128)
+    kx, kt = jax.random.split(jax.random.PRNGKey(m * 31 + n_in + r))
+    x = jax.random.normal(kx, (m, n_in), dtype)
+    t = jnp.where(jax.random.bernoulli(kt, 0.5, (r, n_in)), 1.0, -1.0)
+    packed = pack_bits(t)                          # (r, ceil(n_in/32))
+    got = _dense_unique_local(
+        x, packed, n_in=n_in, use_pallas=True,
+        block_m=128, block_r=128, block_k=4096,
+    )
+    want = tiled_matvec_unique_ref(x.astype(jnp.float32), packed, n_in=n_in)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("k,block_k", [(4096, 4096), (16384, 4096),
+                                       (8192, 8192), (2048, 1024)])
+def test_planar_columns_is_the_column_gather(k, block_k):
+    """planar_columns puts input column 32j + b of each k block at block
+    column b·nw + j; undoing the gather restores x."""
+    from repro.kernels.tiled_matmul import is_planar, planar_columns
+
+    nw = block_k // 32
+    assert is_planar(block_k) == (nw % 128 == 0)
+    x = jax.random.normal(jax.random.PRNGKey(k + block_k), (3, k))
+    src = np.array([blk * block_k + 32 * j + b
+                    for blk in range(k // block_k)
+                    for b in range(32) for j in range(nw)])
+    got = np.asarray(planar_columns(x, block_k))
+    np.testing.assert_array_equal(got, np.asarray(x)[:, src])
+    back = np.empty_like(got)
+    back[:, src] = got
+    np.testing.assert_array_equal(back, np.asarray(x))
+
+
 def test_decode_dispatch_matches_matmul_blocking():
     """tiled_dense_infer at decode m equals the same call forced through
     the reference math — the dispatch changes blocking, not results."""

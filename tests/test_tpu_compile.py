@@ -13,6 +13,9 @@ The topology is described inside a module fixture, never at import:
 only one process may hold the TPU library at a time, so a test worker
 that is not given this file must not load it.
 """
+import base64
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,6 +41,10 @@ GRANITE_LAYERS = {
     "down": (14336, 4096),
     "head": (4096, 49152),
 }
+# the tile kernels' function names; the benchmark attributes a Pallas call
+# to the one its serialized body names most
+KERNEL_FNS = {"matvec": b"_matvec_kernel", "matmul": b"_matmul_kernel"}
+_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +84,19 @@ def _compile(fn, *args):
     return compiled
 
 
+def _kernel_name_counts(hlo_text):
+    """Per ``tpu_custom_call`` of a compiled module, how often its
+    serialized body names each tile kernel function."""
+    out = []
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        body = _BODY.search(line).group(1)
+        raw = base64.b64decode(body + "=" * (-len(body) % 4))
+        out.append({k: raw.count(fn) for k, fn in KERNEL_FNS.items()})
+    return out
+
+
 def _rows(n_in, n_out, sharding):
     r = n_out // P_TILE
     return jax.ShapeDtypeStruct((r, -(-n_in // 32)), jnp.int32,
@@ -85,22 +105,33 @@ def _rows(n_in, n_out, sharding):
 
 @pytest.mark.parametrize(
     "m,compute_path",
-    [(256, "float"), (16, "float"), (16, "int8"), (16, "xnor")],
-    ids=["matmul-prefill", "matvec-decode", "int8-decode", "xnor-decode"],
+    [(256, "float"), (512, "float"), (16, "float"), (16, "int8"),
+     (16, "xnor")],
+    ids=["matmul-prefill", "matmul-extend", "matvec-decode", "int8-decode",
+         "xnor-decode"],
 )
 @pytest.mark.parametrize("layer", sorted(GRANITE_LAYERS))
 def test_dense_kernels_compile_at_granite_widths(
         one_chip, on_tpu, layer, m, compute_path):
     """The serve dispatch (ops._dense_unique_local): padding, block choice
-    and kernel, for prefill m=256 (matmul) and decode m=16 (matvec and
-    the two integer paths)."""
+    and kernel, for prefill m=256 and an extend tick's m=512 (8 slots x
+    chunk 64; matmul) and decode m=16 (8 slots, bf16-rounded: matvec and
+    the two integer paths), at the default blocks with no VMEM limit
+    raised, so Mosaic refuses blocks and unpack temporaries that overrun
+    the default scoped VMEM. A float call's body must name its own
+    kernel function more often than the other's: the benchmark
+    attributes each call to a kernel by that count."""
     n_in, n_out = GRANITE_LAYERS[layer]
     x = jax.ShapeDtypeStruct((m, n_in), jnp.bfloat16, sharding=one_chip)
-    _compile(
+    compiled = _compile(
         lambda x, t: _dense_unique_local(
             x, t, n_in=n_in, use_pallas=True, block_m=128, block_r=128,
             block_k=4096, compute_path=compute_path),
         x, _rows(n_in, n_out, one_chip))
+    if compute_path == "float":
+        (counts,) = _kernel_name_counts(compiled.as_text())
+        own, other = ("matvec", "matmul") if m <= 32 else ("matmul", "matvec")
+        assert counts[own] > counts[other], counts
 
 
 @pytest.mark.parametrize("m", [256, 8])
