@@ -1,16 +1,19 @@
 """Finds everything a cell needs by the names in ``BENCHMARK.json``.
 
 - a configuration: ``perfbench/configs/<config>.json``, whose
-  ``reference`` names its plain reference, ``perfbench/references/
-  <reference>.py``;
+  ``family`` names its family module, ``perfbench/families/<family>.py``
+  (everything the benchmark knows of a model's layout: the keys that fix
+  its math, its shipped weights, the program's serving model, and the
+  operations its steps and kernels need), and whose ``reference`` names
+  its plain reference, ``perfbench/references/<reference>.py``;
 - a traffic mix: ``perfbench/traffic/<traffic>.json``;
 - an end-to-end metric: ``perfbench/e2e/<name>.py``;
 - a per-layer metric: ``perfbench/metrics/<name>.py``.
 
 Each metric file defines ``read(run) -> float | None``: ``None`` when it
 finds nothing to read, and the metric is then left out of the line. A new
-cell, mix or metric is new files and a new entry in ``BENCHMARK.json``;
-no existing file changes.
+cell, mix, metric or model family is new files and a new entry in
+``BENCHMARK.json``; no existing file changes.
 """
 from __future__ import annotations
 
@@ -43,6 +46,9 @@ class Registry:
     def traffic(self, name: str) -> dict:
         return json.loads(
             (self.bench_dir / "traffic" / f"{name}.json").read_text())
+
+    def family(self, name: str) -> ModuleType:
+        return _load(self.bench_dir / "families" / f"{name}.py")
 
     def reference(self, name: str) -> ModuleType:
         return _load(self.bench_dir / "references" / f"{name}.py")

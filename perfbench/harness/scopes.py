@@ -12,6 +12,13 @@ operation of a tick run is charged to the innermost scope its
 instruction names, and to ``unscoped`` where it names none (copies the
 compiler inserted carry no metadata at all). The buckets partition the
 tick's leaf operation time exactly.
+
+A reader may also name a scope that ``SCOPES`` lacks, such as a part of
+one layer (``experts``, or the path ``ffn/experts``): an operation is
+charged to it where it is the innermost of ``SCOPES`` and that scope in
+the operation's path. Operations outside it are charged as they are
+without it, so the buckets of ``SCOPES`` outside its parent read the
+same.
 """
 from __future__ import annotations
 
@@ -27,35 +34,44 @@ _NAME = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = ")
 _OP_NAME = re.compile(r'metadata=\{op_name="([^"]*)"')
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost component of an ``op_name`` path that is a scope."""
-    for part in reversed(op_name.split("/")):
-        if part in SCOPES:
-            return part
+def scope_of(op_name: str, extra: str = "") -> str:
+    """The innermost scope of an ``op_name`` path: a component in
+    ``SCOPES``, or ``extra`` (one component or a path of several) where
+    it ends at or after the innermost such component."""
+    parts = op_name.split("/")
+    want = extra.split("/") if extra else []
+    for i in range(len(parts), 0, -1):
+        if want and parts[max(i - len(want), 0):i] == want:
+            return extra
+        if parts[i - 1] in SCOPES:
+            return parts[i - 1]
     return UNSCOPED
 
 
-def instruction_scopes(hlo_text: str) -> Dict[str, str]:
+def instruction_scopes(hlo_text: str, extra: str = "") -> Dict[str, str]:
     """``{instruction: scope}`` of every instruction of a compiled
-    module's text."""
+    module's text (``extra`` as in ``scope_of``)."""
     out = {}
     for line in hlo_text.splitlines():
         name = _NAME.match(line)
         if not name:
             continue
         meta = _OP_NAME.search(line)
-        out[name.group(1)] = scope_of(meta.group(1)) if meta else UNSCOPED
+        out[name.group(1)] = scope_of(meta.group(1), extra) if meta \
+            else UNSCOPED
     return out
 
 
 def device_ms(run, tick: str, scope: str) -> Optional[float]:
     """Device time per run of ``tick`` (``decode_tick`` | ``extend_tick``)
-    in the leaf operations charged to ``scope`` (one of ``SCOPES`` or
-    ``unscoped``), in ms. ``None`` without a trace, without a run of the
-    tick on it, or for a program whose tick names no scope."""
+    in the leaf operations charged to ``scope`` (one of ``SCOPES``,
+    ``unscoped``, or another scope, read as the module docstring says),
+    in ms. ``None`` without a trace, without a run of the tick on it, or
+    for a program whose tick names no scope."""
     if run.trace is None or tick not in run.hlo:
         return None
-    table = instruction_scopes(run.hlo[tick])
+    extra = "" if scope in SCOPES or scope == UNSCOPED else scope
+    table = instruction_scopes(run.hlo[tick], extra)
     if all(s == UNSCOPED for s in table.values()):
         return None
     runs = sum(trace.module_matches(m.module, tick)

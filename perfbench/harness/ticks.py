@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from harness import counting, trace
+from harness import trace
 
 SPAN = {"decode_tick": "decode", "extend_tick": "extend"}
 
@@ -22,15 +22,15 @@ def device_ms(run, tick: str) -> Optional[float]:
 
 
 def mfu(run, tick: str) -> Optional[float]:
-    """Operations the window's ``tick`` calls needed (``counting``), over
-    the host-clock time of the benchmark's spans around them times the
-    chip's peak, in %."""
+    """Operations the window's ``tick`` calls needed (the family's
+    ``step_flops``), over the host-clock time of the benchmark's spans
+    around them times the chip's peak, in %."""
     if run.peaks is None:
         return None
     spans = [s for s in run.spans if s.name == SPAN[tick]]
     wall = sum(s.t1 - s.t0 for s in spans)
     if not spans or wall <= 0:
         return None
-    flops = sum(counting.step_flops(run.cfg, s.rows, s.key_pairs,
-                                    s.logit_rows) for s in spans)
+    flops = sum(run.family.step_flops(run.cfg, s.rows, s.key_pairs,
+                                      s.logit_rows) for s in spans)
     return 100.0 * flops / (wall * run.peaks.flops_bf16)

@@ -3,9 +3,11 @@
     python3 perfbench/run.py --workload granite-8b.chat --seed 7 \
         --seconds 40 --trace 0
 
-Set-up draws the cell's weights on the chip from ``--seed``, builds the
-program's ``BatchedEngine`` behind its ``EngineServer``, compiles the two
-tick executables (checking that both hold the Pallas tile kernels), and
+Set-up draws the cell's weights on the chip from ``--seed`` and builds the
+program's serving model, both as the configuration's family says
+(``perfbench/families/<family>.py``), puts the program's
+``BatchedEngine`` behind its ``EngineServer``, compiles the two tick
+executables (checking that both hold the Pallas tile kernels), and
 sends warm-up requests over the wire. Then a child process that imports
 no JAX replays the cell's traffic against ``POST /generate`` for
 ``--seconds``; that is the measured window. After it, the served tokens
@@ -27,7 +29,6 @@ T_PROC = time.perf_counter()
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -46,35 +47,6 @@ sys.path.insert(0, str(ROOT / "src"))
 # (a traffic file's ``trace_at`` overrides it), and how long the trace lasts
 TRACE_AT = 0.4
 TRACE_SECONDS = 3.0
-
-
-def model_config(cfgd: dict) -> dict:
-    """The keys of a configuration file that fix the model's math."""
-    keys = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "intermediate_size",
-            "vocab_size", "hidden_act", "gated_mlp", "norm", "rope_theta",
-            "qkv_bias", "tie_word_embeddings", "tbn_p")
-    return {k: cfgd[k] for k in keys}
-
-
-def program_model(cfgd: dict):
-    """The program's serving model for a configuration file."""
-    from repro.configs import build_model, get_config
-    from repro.nn.context import SERVE, ModelContext
-
-    arch = get_config(cfgd["program_arch"])
-    cfg = dataclasses.replace(
-        arch, name=cfgd["name"], n_layers=cfgd["num_hidden_layers"],
-        d_model=cfgd["hidden_size"], n_heads=cfgd["num_attention_heads"],
-        n_kv=cfgd["num_key_value_heads"], head_dim=cfgd["head_dim"],
-        d_ff=cfgd["intermediate_size"], vocab=cfgd["vocab_size"],
-        activation=cfgd["hidden_act"], gated_mlp=cfgd["gated_mlp"],
-        norm=cfgd["norm"], qkv_bias=cfgd["qkv_bias"],
-        rope_theta=float(cfgd["rope_theta"]),
-        tie_embeddings=cfgd["tie_word_embeddings"],
-        tbn=dataclasses.replace(arch.tbn, p=cfgd["tbn_p"],
-                                min_size=cfgd["tbn_min_size"]))
-    return build_model(cfg, ModelContext(policy=cfg.tbn, mode=SERVE))
 
 
 def server_config(traffic: dict, seed: int):
@@ -109,7 +81,7 @@ def main(argv=None, *, allow_cpu: bool = False, fault=None,
 
     import jax
 
-    from harness import check, serving, trace, traffic as gen, weights
+    from harness import check, serving, trace, traffic as gen
     from harness.peaks import peaks
     from harness.registry import Registry, read_all
 
@@ -130,14 +102,15 @@ def main(argv=None, *, allow_cpu: bool = False, fault=None,
     from repro.serve.engine import BatchedEngine
 
     cfgd = reg.config(cell["config"])
-    mcfg = model_config(cfgd)
+    fam = reg.family(cfgd["family"])
+    mcfg = {k: cfgd[k] for k in fam.KEYS}
     mix = reg.traffic(cell["traffic"])
     compiles = serving.Compiles()
 
     phases = {"start": time.perf_counter() - T_PROC}
-    params = weights.build(mcfg, args.seed)
+    params = fam.build(mcfg, args.seed)
     phases["weights"] = time.perf_counter() - T_PROC
-    model = program_model(cfgd)
+    model = fam.program(cfgd)
     eng = BatchedEngine(model, params, server_config(mix, args.seed))
     compile_s = eng.warmup()
     phases["ticks"] = time.perf_counter() - T_PROC
@@ -185,8 +158,8 @@ def main(argv=None, *, allow_cpu: bool = False, fault=None,
         records=win.records, seconds=args.seconds, setup_s=setup_s,
         start=win.start, close=win.close, stats0=win.stats0,
         stats1=win.stats1, spans=window_spans,
-        trace=tr, trace_window=win.trace_window, cfg=mcfg, mix=mix,
-        peaks=peaks(devs[0].device_kind) if on_tpu else None, hlo=hlo,
+        trace=tr, trace_window=win.trace_window, cfg=mcfg, family=fam,
+        mix=mix, peaks=peaks(devs[0].device_kind) if on_tpu else None, hlo=hlo,
         schedule=schedule)
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = read_all(reg, kind, args.workload, run)

@@ -7,10 +7,13 @@ the seed, the program's engine and server, the load client in its own
 process, the window, the reference check) with the look for a chip
 skipped; the program's tile layers take their structured path off the
 chip. The same run with the timed path broken underneath has to come out
-``correct: false``.
+``correct: false``. A second model family, a small mixture of experts,
+goes the same way from new files alone.
 """
 from __future__ import annotations
 
+import base64
+import hashlib
 import importlib.util
 import io
 import json
@@ -18,6 +21,7 @@ import pathlib
 import shutil
 import sys
 from contextlib import redirect_stdout
+from types import SimpleNamespace
 
 import pytest
 
@@ -27,36 +31,52 @@ DATA = BENCH / "tests" / "data"
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(REPO / "src"))
 
-from harness import counting, trace, traffic  # noqa: E402
+from harness import counting, kernels, trace, traffic  # noqa: E402
+from harness.peaks import peaks  # noqa: E402
 from harness.registry import Registry  # noqa: E402
 
 TINY_CELL = {"name": "tiny.chat", "config": "tiny", "traffic": "tiny.chat",
              "chips": 1, "why": "test-only tiny cell"}
+MOE_CELL = {"name": "tiny-moe.chat", "config": "tiny-moe",
+            "traffic": "tiny-moe.chat", "chips": 1,
+            "why": "test-only tiny cell of a second model family"}
+# the tiny cells' files: (name under tests/data, path under perfbench/)
+DROPPED = [("tiny.json", "configs/tiny.json"),
+           ("tiny.chat.json", "traffic/tiny.chat.json"),
+           ("tiny-moe.json", "configs/tiny-moe.json"),
+           ("tiny-moe.chat.json", "traffic/tiny-moe.chat.json"),
+           ("moe_decoder_family.py", "families/moe_decoder.py"),
+           ("moe_decoder_reference.py", "references/moe_decoder.py")]
 
 
 @pytest.fixture()
 def root(tmp_path):
-    """A checkout holding the benchmark as committed, plus a tiny cell
-    dropped in as new files only: its configuration, its traffic mix, a
-    new per-layer metric and their entries in BENCHMARK.json."""
+    """A checkout holding the benchmark as committed, plus two tiny cells
+    dropped in as new files only: their configurations, traffic mixes,
+    the second cell's model family and reference, a new per-layer metric,
+    and their entries in BENCHMARK.json."""
     shutil.copytree(BENCH, tmp_path / "perfbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     (tmp_path / "src").symlink_to(REPO / "src")
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
-    shutil.copy(DATA / "tiny.json", tmp_path / "perfbench/configs/tiny.json")
-    shutil.copy(DATA / "tiny.chat.json",
-                tmp_path / "perfbench/traffic/tiny.chat.json")
+    for src, dst in DROPPED:
+        assert not (tmp_path / "perfbench" / dst).exists(), dst
+        shutil.copy(DATA / src, tmp_path / "perfbench" / dst)
     (tmp_path / "perfbench/metrics/requests_finished.py").write_text(
         "def read(run):\n"
         "    return sum(r['finished'] for r in run.records)\n")
-    spec["configs"].append({"name": "tiny", "source": "test",
-                            "file": "perfbench/configs/tiny.json",
-                            "reduced": [], "why": "test"})
-    spec["workloads"].append(TINY_CELL)
+    for name in ("tiny", "tiny-moe"):
+        spec["configs"].append({"name": name, "source": "test",
+                                "file": f"perfbench/configs/{name}.json",
+                                "reduced": [], "why": "test"})
+    spec["workloads"] += [TINY_CELL, MOE_CELL]
+    for m in spec["end_to_end"]:
+        if "workloads" in m:    # the tiny cells report every one of them
+            m["workloads"] += [TINY_CELL["name"], MOE_CELL["name"]]
     spec["per_layer"].append({
         "name": "requests_finished", "unit": "requests", "better": "higher",
         "source": "program_counter", "layer": "front end",
-        "moves": "itl_p50_ms", "workloads": ["tiny.chat"]})
+        "moves": "itl_p50_ms", "workloads": ["tiny.chat", "tiny-moe.chat"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     return tmp_path
 
@@ -70,11 +90,11 @@ def _run_module(root: pathlib.Path):
 
 
 def run_cell(root, seed=3, seconds=4.0, trace_on=0, fault=None,
-             control=""):
+             control="", workload="tiny.chat"):
     mod = _run_module(root)
     out = io.StringIO()
     with redirect_stdout(out):
-        rc = mod.main(["--workload", "tiny.chat", "--seed", str(seed),
+        rc = mod.main(["--workload", workload, "--seed", str(seed),
                        "--seconds", str(seconds), "--trace", str(trace_on)],
                       allow_cpu=True, fault=fault, control=control)
     assert rc == 0
@@ -82,26 +102,148 @@ def run_cell(root, seed=3, seconds=4.0, trace_on=0, fault=None,
 
 
 # ---------------------------------------------------------------- units --
+def _family(name):
+    return Registry(BENCH.parent).family(name)
+
+
 def test_unique_row_count_of_one_granite_layer():
     """By hand: granite-8b's q (4096 -> 4096), k and v (4096 -> 1024), o
     (4096 -> 4096), gate and up (4096 -> 14336), down (14336 -> 4096),
     p = 8, on m = 16 rows: 2 * m * (n_out / 8) * n_in each."""
     cfg = json.loads((BENCH / "configs" / "granite-8b.json").read_text())
+    fam = _family(cfg["family"])
     m = 16
     want = 2 * m * (512 * 4096 * 2 + 128 * 4096 * 2 + 1792 * 4096 * 2
                     + 512 * 14336)
     got = sum(counting.tiled_linear(m, r, k, 8)[0]
-              for r, k in counting.layer_shapes(cfg))
+              for r, k in fam.layer_shapes(cfg))
     assert got == want
     # bytes of the down projection: tile, alphas, bf16 in, f32 out
     _, nbytes = counting.tiled_linear(m, 512, 14336, 8)
     assert nbytes == 512 * 448 * 4 + 8 * 4 + m * 14336 * 2 + m * 512 * 4
     # a whole step: layers, attention pairs, head
-    step = counting.step_flops(cfg, m, key_pairs=100, logit_rows=m)
+    step = fam.step_flops(cfg, m, key_pairs=100, logit_rows=m)
     assert step == 36 * (want + 4 * 32 * 128 * 100) + 2 * m * 6144 * 4096
     # padded kernel shapes map back to the layer they serve
-    assert counting.match_linear(cfg, 512, 16384) == (512, 14336)
-    assert counting.match_linear(cfg, 1792, 4096) == (1792, 4096)
+    assert counting.closest(fam.tiled_shapes(cfg), 512, 16384) == \
+        (512, 14336)
+    assert counting.closest(fam.tiled_shapes(cfg), 1792, 4096) == \
+        (1792, 4096)
+
+
+def test_unique_row_count_of_the_test_moe_family():
+    """By hand, the tiny MoE (d 128, p 4, 3 layers, the first dense): per
+    row, attention q/o (32 x 128 unique rows each) and k/v (16 x 128);
+    the dense MLP 64/64/32 rows; in each MoE layer the router (4 x 128),
+    2 picked experts and 1 shared, 32/32/32 rows each; the head 1024."""
+    cfg = json.loads((DATA / "tiny-moe.json").read_text())
+    spec = importlib.util.spec_from_file_location(
+        "moe_family", DATA / "moe_decoder_family.py")
+    fam = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fam)
+    m, pairs = 4, 10
+    attn = 2 * m * (32 * 128 * 2 + 16 * 128 * 2) + 4 * 4 * 32 * pairs
+    dense = 2 * m * (64 * 128 * 2 + 32 * 256)
+    expert = 2 * m * 32 * 128 * 3
+    moe = 2 * m * 4 * 128 + 2 * expert + expert
+    assert fam.step_flops(cfg, m, pairs, 1) == \
+        3 * attn + dense + 2 * moe + 2 * 1 * 1024 * 128
+    assert counting.closest(fam.tiled_shapes(cfg), 64, 128) == (64, 128)
+    assert counting.closest(fam.tiled_shapes(cfg), 32, 256) == (32, 256)
+
+
+def _digest(params) -> str:
+    import jax
+    import numpy as np
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.dtype).encode())
+        h.update(str(a.shape).encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+# sha256 of the tiny configuration's weights (every leaf's path, dtype,
+# shape and bytes, in tree order) as the harness drew them before the
+# draw moved into the family module, on the CPU
+WEIGHT_DIGESTS = {
+    3: "5651f80549aac0b622e930336c37d383f3b0438850f4445777e701ac27503717",
+    2**33 + 5:
+        "4fe7f34f30e0807b290d593cca3ad33b9f501db1ff6c9ab8e3fac99db818e0eb",
+}
+
+
+@pytest.mark.parametrize("seed", sorted(WEIGHT_DIGESTS))
+def test_dense_family_draws_the_weights_it_always_drew(seed):
+    cfgd = json.loads((DATA / "tiny.json").read_text())
+    fam = _family(cfgd["family"])
+    cfg = {k: cfgd[k] for k in fam.KEYS}
+    assert _digest(fam.build(cfg, seed)) == WEIGHT_DIGESTS[seed]
+
+
+def _kernel_hlo(tick: str, fn: bytes, x: tuple, tile: tuple) -> str:
+    """A compiled tick whose one instruction, ``fusion`` (an operation of
+    the recorded trace), is a Pallas call of kernel function ``fn``."""
+    body = base64.b64encode(
+        b"module { func @" + fn + b"() loc(" + fn + b") }").decode()
+    return (f"HloModule jit__{tick}\n\nENTRY %main () -> () {{\n"
+            f"  ROOT %fusion = f32[{x[0]},{tile[0]}]{{1,0}} custom-call("
+            f'%x, %t), custom_call_target="tpu_custom_call", '
+            f"operand_layout_constraints={{bf16[{x[0]},{x[1]}]{{1,0}}, "
+            f"s32[{tile[0]},{tile[1]}]{{1,0}}}}, backend_config={{"
+            f'"custom_call_config": {{"body":"{body}"}}}}\n}}\n')
+
+
+# config: (decode call x and tile shapes, extend call's), step_flops at
+# (rows, key_pairs, logit_rows), rooflines on the recorded trace: the
+# harness's readings before the counts moved into the family module
+RECORDED_COUNTS = {
+    "configs/granite-8b.json": (
+        [((16, 16384), (512, 512)), ((512, 4096), (1792, 128))],
+        {(16, 100, 16): 32271237120.0, (512, 70000, 3): 1046461022208.0,
+         (8, 20000, 8): 27902607360.0},
+        {"tiled_matvec": 51.772766042859,
+         "tiled_matmul": 811.2106050438717}),
+    "tests/data/tiny.json": (
+        [((8, 256), (32, 8)), ((256, 128), (64, 4))],
+        {(16, 100, 16): 7886848.0, (512, 70000, 3): 221552640.0,
+         (8, 20000, 8): 34586624.0},
+        {"tiled_matvec": 0.2741522697782949,
+         "tiled_matmul": 3.429732914663548}),
+}
+
+
+@pytest.mark.parametrize("config", sorted(RECORDED_COUNTS))
+def test_dense_family_counts_what_the_harness_always_counted(config):
+    (xd, td), (xe, te) = RECORDED_COUNTS[config][0]
+    cfgd = json.loads((BENCH / config).read_text())
+    fam = _family(cfgd["family"])
+    cfg = {k: cfgd[k] for k in fam.KEYS}
+    for args, want in RECORDED_COUNTS[config][1].items():
+        assert fam.step_flops(cfg, *args) == want, args
+    run = SimpleNamespace(
+        trace=trace.load(str(DATA / "tiny_tpu.xplane.pb")),
+        peaks=peaks("TPU v5 lite"), cfg=cfg, family=fam,
+        mix={"server": {"n_slots": 8, "chunk_tokens": 64}},
+        hlo={"decode_tick": _kernel_hlo("decode_tick", b"_matvec_kernel",
+                                        xd, td),
+             "extend_tick": _kernel_hlo("extend_tick", b"_matmul_kernel",
+                                        xe, te)})
+    for kernel, want in RECORDED_COUNTS[config][2].items():
+        assert kernels.roofline(run, kernel) == want, kernel
+    assert kernels.roofline(run, "no_such_kernel") is None
+
+
+def test_harness_names_no_model_layout():
+    """What a model family knows stays in its family module: the harness
+    and the entry point name none of the dense decoder's keys or leaves."""
+    words = ("num_key_value_heads", "intermediate_size", "head_dim", "wq",
+             "gated_mlp")
+    for path in sorted((BENCH / "harness").glob("*.py")) + [BENCH / "run.py"]:
+        text = path.read_text()
+        assert not [w for w in words if w in text], path.name
 
 
 BACKLOG = {"kind": "backlog", "waiting_per_slot": 2,
@@ -176,7 +318,8 @@ def test_a_sound_run_is_correct(root):
     res = run_cell(root)
     assert res["correct"], res
     assert res["attempted"] > 10 and res["failed"] == 0
-    assert set(res["metrics"]) >= {"itl_p50_ms", "itl_p95_ms", "setup_s"}
+    assert set(res["metrics"]) >= {"itl_p50_ms", "itl_p95_ms",
+                                    "itl_mean_ms", "setup_s"}
     assert list(res)[-1] == "check"
     assert res["device"]["platform"] == "cpu"
 
@@ -247,3 +390,18 @@ def test_the_control_is_not_correct(root):
     tokens, fails the limit."""
     res = run_cell(root, seed=7, control="fp8")
     assert not res["correct"], res["check"]
+
+
+@pytest.mark.parametrize("broken", [{}, {"fault": _altered_token},
+                                    {"control": "fp8"}],
+                         ids=["sound", "token_altered", "control"])
+def test_a_new_family_runs_from_new_files_alone(root, broken):
+    """The tiny MoE cell, whose model family, reference, configuration and
+    traffic mix the fixture drops in as new files, goes through the whole
+    run: correct as it is; not correct with the timed path broken, or
+    with the reference in fp8 in the program's place. (Its limit, 0.15,
+    lies between the widest gaps of sound runs on the CPU, 0.009-0.069 on
+    10 seeds, and the fp8 control's, 0.36-0.92 on 4.)"""
+    res = run_cell(root, seed=13, workload="tiny-moe.chat", **broken)
+    assert res["correct"] is (not broken), res["check"]
+    assert res["attempted"] > 10 and res["failed"] == 0

@@ -105,6 +105,27 @@ def test_scopes_partition_the_tick_leaf_time_exactly():
             assert got is None, name
 
 
+@pytest.mark.parametrize("sub", ["experts", "ffn/experts"])
+def test_a_scope_outside_SCOPES_reads_its_subset(sub):
+    """A reader may name a part of a scope (the MoE's experts inside
+    ``ffn``): it reads the operations under it, and every scope of
+    ``SCOPES`` reads as before."""
+    hlo = DECODE_HLO.replace("/ffn/pallas_call", "/ffn/experts/pallas_call")
+    hlo = hlo.replace("/attn/dot_general", "/experts/attn/dot_general")
+    plain = SimpleNamespace(trace=_decode_trace(), hlo={"decode_tick":
+                                                        DECODE_HLO})
+    run = SimpleNamespace(trace=_decode_trace(), hlo={"decode_tick": hlo})
+    assert scopes.device_ms(run, "decode_tick", sub) == pytest.approx(100e-6)
+    for s in scopes.SCOPES + (scopes.UNSCOPED,):
+        assert scopes.device_ms(run, "decode_tick", s) == \
+            scopes.device_ms(plain, "decode_tick", s), s
+    assert scopes.device_ms(run, "decode_tick", "ffn") == pytest.approx(100e-6)
+    assert scopes.device_ms(run, "decode_tick", "attn/experts") == 0.0
+    assert scopes.scope_of("jit(t)/ffn/experts/dot", "ffn/experts") == \
+        "ffn/experts"
+    assert scopes.scope_of("jit(t)/experts/ffn/dot", "ffn/experts") == "ffn"
+
+
 def test_scope_metrics_are_absent_for_a_program_without_scopes():
     """A program whose ticks name no scope (as before the scopes were
     added) reads nothing, rather than all of its time as unscoped."""
